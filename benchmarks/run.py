@@ -45,6 +45,9 @@ def main() -> None:
 
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 
+    from repro.common.jaxcache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_alter_ratio,
         bench_autotune,

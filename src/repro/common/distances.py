@@ -16,13 +16,16 @@ def squared_l2(a: Array, b: Array) -> Array:
     """Pairwise squared L2 between rows of ``a`` (A, d) and ``b`` (B, d).
 
     Uses the matmul expansion ``|a|^2 - 2 a.b + |b|^2`` so the MXU does the
-    heavy lifting; accumulates in f32.
+    heavy lifting; accumulates in f32. The product runs at HIGHEST
+    precision: at the default a TPU multiplies f32 inputs in bf16, which
+    would make the exact oracle, the graph build and the start-point
+    seeding inexact there (on the CPU it is the same f32 product).
     """
     a = a.astype(jnp.float32)
     b = b.astype(jnp.float32)
     a2 = jnp.sum(a * a, axis=-1, keepdims=True)  # (A, 1)
     b2 = jnp.sum(b * b, axis=-1, keepdims=True).T  # (1, B)
-    ab = a @ b.T  # (A, B)
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)  # (A, B)
     d = a2 - 2.0 * ab + b2
     return jnp.maximum(d, 0.0)
 

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map
 from repro.core.constraints import LabelSetConstraint, RangeConstraint
 from repro.core.engine.context import build_context
 from repro.core.engine.loop import search_with_context
@@ -166,8 +165,9 @@ def make_distributed_search(
         )
         return SearchResult(dists=out_d, ids=out_i, stats=stats)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
     jitted = jax.jit(sharded)
     needs_pq = params.approx == "pq"

@@ -53,11 +53,11 @@ from repro.tune.table import lookup as tune_lookup
 Array = jax.Array
 
 
-# Flip to True once the fused kernels have been validated under compiled
-# Mosaic lowering on real hardware (the per-candidate scalar stores and
-# narrow metadata/code DMAs have only ever run in interpret mode on this
-# container). Until then "auto" never routes a default search through an
-# unproven compile path; the fused pipeline is opt-in via fuse_expand="on".
+# The fused kernels compile under Mosaic and run on a v5e chip
+# (chip_smoke.py serves the 1M x 128 deployment with fuse_expand "on" and
+# "off" and checks both against the exact oracle). Whether "auto" should
+# fuse there is a speed question the first chip benchmark answers; until
+# then the fused pipeline stays opt-in via fuse_expand="on".
 FUSE_AUTO_ON_TPU = False
 
 
@@ -68,7 +68,7 @@ def resolve_auto_fuse(fusable: bool, backend: str) -> bool:
     purely physical. On TPU the fused kernel eliminates the separate
     metadata/visited HBM round trips and the windowed sorted merges are
     plain VPU work — that is where auto is meant to fuse, gated on
-    ``FUSE_AUTO_ON_TPU`` until hardware validation. On XLA:CPU,
+    ``FUSE_AUTO_ON_TPU`` until a chip benchmark shows it wins. On XLA:CPU,
     measurement says fusing loses: the native TopK a ``queue_push``
     lowers to is data-dependent (fast on the inf-padded queues real
     traversals carry) and keeps donated-buffer reuse inside

@@ -95,8 +95,8 @@ class SearchParams:
     # constraint + visited masking in one pass, frontier updates via sorted
     # merges instead of top_k re-selection (engine/loop.py). "auto" targets
     # TPU only — and only for constraint families with in-kernel evaluation
-    # (LabelSet / Range) — gated on the hardware-validation flag
-    # FUSE_AUTO_ON_TPU (engine/context.py::resolve_auto_fuse); on other
+    # (LabelSet / Range) — gated on FUSE_AUTO_ON_TPU
+    # (engine/context.py::resolve_auto_fuse); on other
     # backends native top_k wins in-loop so auto stays unfused
     # (EXPERIMENTS.md §Perf PR2). Every distance backend has a fused
     # kernel (exact rows or PQ code rows + in-kernel ADC sums, §Perf PR3);
@@ -104,7 +104,7 @@ class SearchParams:
     # dispatches to the jnp oracle and returns bit-identical results, so
     # "on"/"off" are safe to force; the TPU kernels reduce in a different
     # FP order (ties may break differently) and stay behind
-    # FUSE_AUTO_ON_TPU until validated on hardware.
+    # FUSE_AUTO_ON_TPU until a chip benchmark picks the default.
     fuse_expand: str = static_field(default="auto")  # auto | on | off
     # Beyond-paper: traverse with PQ/ADC approximate distances (PQBackend,
     # 32x fewer HBM bytes per candidate at d=128/m_sub=16), then exact

@@ -20,10 +20,17 @@ import jax.numpy as jnp
 Array = jax.Array
 
 WORD_BITS = 32
+# The fused kernels probe a bitset in aligned 128-word (one vreg row)
+# windows; a bitset wider than one window is allocated in whole windows so
+# they need not pad it on every call.
+WINDOW_WORDS = 128
 
 
 def n_words(n: int) -> int:
-    return (n + WORD_BITS - 1) // WORD_BITS
+    words = (n + WORD_BITS - 1) // WORD_BITS
+    if words <= WINDOW_WORDS:
+        return words
+    return -(-words // WINDOW_WORDS) * WINDOW_WORDS
 
 
 def visited_init(batch: int, n: int) -> Array:

@@ -15,7 +15,7 @@ from dataclasses import dataclass
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 Array = jax.Array
@@ -96,8 +96,13 @@ class MeshInfo:
             for a in axes:
                 size *= self.mesh.shape[a]
             fixed.append(s if dim % size == 0 else None)
-        # Trailing unspecified dims stay unsharded.
-        return jax.lax.with_sharding_constraint(x, self.sharding(*fixed))
+        # Trailing unspecified dims stay unsharded. On a mesh with Explicit
+        # axes (jax.make_mesh's default) the sharding is part of the type
+        # and with_sharding_constraint only asserts it, so reshard there.
+        sharding = self.sharding(*fixed)
+        if AxisType.Explicit in self.mesh.axis_types:
+            return jax.sharding.reshard(x, sharding)
+        return jax.lax.with_sharding_constraint(x, sharding)
 
 
 def single_device_meshinfo() -> MeshInfo:

@@ -1,13 +1,15 @@
 """Pallas TPU kernels for the compute hot-spots of the constrained-search
 system. Each subpackage ships <name>.py (pl.pallas_call + BlockSpec),
 ops.py (jit'd public wrapper with a pure-jnp fallback) and ref.py (the
-oracle the tests assert against). On this CPU container the kernels run
-in interpret mode; BlockSpecs target TPU v5e VMEM/MXU geometry.
+oracle the tests assert against). On a TPU the kernels are compiled by
+Mosaic (tests/test_tpu_compile.py compiles the search-path kernels for a
+described v5e at 1M x 128; chip_smoke.py runs the fused pipeline on the
+chip); on the CPU the tests run them in interpret mode.
 
 Every ops.py wrapper routes through ``dispatch_kernel`` below — the one
 copy of the "Pallas on TPU, jnp oracle elsewhere, interpret-mode Pallas
-for tests/CI smoke" platform policy that used to be duplicated across the
-five wrappers.
+for tests/CI smoke" platform policy. On a TPU it never swaps in the
+oracle or the interpreter.
 """
 from __future__ import annotations
 

@@ -13,21 +13,32 @@ emitting ``(dists, satisfied, fresh)`` without ever materializing the
 (B, M, d) gathered tensor or re-gathering per-candidate metadata.
 
 TPU mapping: the id matrix is *scalar-prefetched* (SMEM) and drives manual
-pipelined row DMAs — unlike ``gather_distance``'s historical layout, the
-grid here is ``(B, M / M_blk)`` with lane-aligned ``(1, M_blk)`` output
-tiles: each grid step streams ``M_blk`` corpus rows (plus their 4-byte
-metadata words) through a ``dma_depth``-slot VMEM ring buffer, overlapping
-up to ``dma_depth - 1`` upcoming row copies with the current row's VPU
-distance reduction. The per-query operands (query row, constraint words /
-bounds, visited-bitset words) ride along as (1, ·) VMEM blocks revisited
-across the inner grid axis.
+pipelined row DMAs over a ``(B / QB, M / M_blk)`` grid. Each grid step owns
+QB = ``min(8, B)`` queries (one f32 sublane tile) and an ``(QB, M_blk)``
+output tile; it streams its ``QB * M_blk`` candidates' corpus rows through
+a ``dma_depth``-slot VMEM ring and their 4-byte metadata words through an
+SMEM ring, overlapping up to ``dma_depth - 1`` upcoming copies with the
+current row's VPU distance reduction. The per-query operands (query rows,
+constraint words / bounds, visited-bitset words) ride along as (QB, ·)
+VMEM blocks revisited across the inner grid axis. Three layout rules of
+the TPU compiler shape the body:
 
-Block shapes are no longer fixed: ``m_blk`` (an output-tile-width CAP,
-resolved as ``min(m_blk, round_up(m, 8))``), ``dma_depth`` (2..4) and the
-ADC kernel's ``lut_tile`` come from ``repro.tune.KernelConfig`` via the
-ops.py wrappers — the autotuner (DESIGN.md §11) sweeps that lattice and
-every point is bit-identical by construction: tiling/pipelining only
-reorders DMAs, never the per-candidate arithmetic.
+  * every block's last two dims are multiples of (8, 128) or the whole
+    array (``M_blk`` is one tile of ``round_up(M, 8)`` or whole 128-lane
+    tiles — ``repro.tune.config.lane_tile``);
+  * VMEM cannot take scalar stores, so the three output tiles are carried
+    through the candidate loop as values and stored once;
+  * VMEM cannot load one word at a dynamic lane, so a bitmap probe loads
+    the aligned 128-word window holding the word and picks it by lane
+    compare (``_bit``); bitmaps wider than one window are padded to whole
+    windows (``core.visited`` allocates them that way).
+
+Block shapes are no longer fixed: ``m_blk`` (an output-tile-width CAP),
+``dma_depth`` (2..4) and the ADC kernel's ``lut_tile`` come from
+``repro.tune.KernelConfig`` via the ops.py wrappers — the autotuner
+(DESIGN.md §11) sweeps that lattice and every point is bit-identical by
+construction: tiling/pipelining only reorders DMAs, never the
+per-candidate arithmetic.
 
 Two distance variants share the layout (PR3):
 
@@ -35,21 +46,21 @@ Two distance variants share the layout (PR3):
   * ``fused_expand_adc_kernel`` — PQ/ADC: the DMA streams (1, m_sub) *code*
     rows (m_sub words instead of d floats — 32x fewer HBM bytes at d=128,
     m_sub=16) and the distance is a per-subspace LUT gather + sum against
-    the query's (m_sub, n_cent) ADC table, VMEM-resident per query. The
-    gather is a one-hot compare-select-reduce (``broadcasted_iota`` against
-    the code row) — plain VPU work, no dynamic VMEM indexing — evaluated in
-    ``lut_tile``-column slices when tiled. Each code row selects exactly one
-    column per subspace, so per-row slice sums reduce at most one non-zero
-    against exact +0.0 padding (LUT entries are squared distances, never
-    -0.0): every ``lut_tile`` produces identical bits.
+    the query's ADC table, held transposed as (n_cent, m_sub) so the code
+    row broadcasts down the sublanes. The gather is a one-hot
+    compare-select-reduce (``broadcasted_iota`` against the code row) —
+    plain VPU work, no dynamic VMEM indexing — evaluated in
+    ``lut_tile``-row slices when tiled. Each code row selects exactly one
+    entry per subspace, picked by a max over -inf padding, which is exact:
+    every ``lut_tile`` produces identical bits.
 
 Constraint families (static ``family`` switch, one compiled kernel each):
 
-  * ``"label"`` — LabelSet bitmask: meta table is the (n, 1) int32 label
+  * ``"label"`` — LabelSet bitmask: meta table is the (n,) int32 label
     column, per-query operand is the (B, Lw) uint32 allowed-label words.
-  * ``"range"`` — numeric window: meta table is the (n, 1) f32 attribute
+  * ``"range"`` — numeric window: meta table is the (n,) f32 attribute
     column, per-query operand is the (B, 2) f32 [lo, hi] bounds.
-  * ``"udf"``   — precompiled predicate table: meta is the (n, 1) int32
+  * ``"udf"``   — precompiled predicate table: meta is the (n,) int32
     verdict column (the UDF evaluated over every vertex at table-build
     time — core/constraints.py), non-zero means satisfied. There is no
     per-query operand; the cons block is a (1, 1) dummy pinned to block
@@ -68,9 +79,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.tune.config import lane_tile
+
 Array = jax.Array
 
 WORD_BITS = 32
+LANES = 128
+QUERY_BLOCK = 8  # f32 sublane tile: queries per grid step
 
 FAMILIES = ("label", "range", "udf")
 
@@ -79,135 +94,238 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-def _resolve_m_blk(m_blk: int | None, m: int) -> int:
-    """m_blk is a cap on the lane-aligned output-tile width: small candidate
-    batches collapse to one tile (the pre-autotuner default behaviour)."""
-    return min(m_blk if m_blk is not None else 128, _round_up(m, 8))
+def query_block(b: int) -> int:
+    """Queries per grid step: one sublane tile, or the whole (smaller) batch."""
+    return min(b, QUERY_BLOCK)
 
 
-def _unvisited(vis_ref, cid):
-    """Probe one word of the per-query visited bitset (VMEM-resident)."""
-    sid = jnp.maximum(cid, 0)
-    vword = vis_ref[0, sid // WORD_BITS]
-    vbit = (sid % WORD_BITS).astype(jnp.uint32)
-    return ((vword >> vbit) & jnp.uint32(1)) == jnp.uint32(0)
+def pad_rows(x: Array, rows: int, value=0) -> Array:
+    """Pad axis 0 of ``x`` up to ``rows`` (a no-op when already that tall)."""
+    if x.shape[0] == rows:
+        return x
+    pad = [(0, rows - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    return jnp.pad(x, pad, constant_values=value)
+
+
+def _bitmap(words: Array) -> Array:
+    """(R, W) uint32 bitmap -> int32 (a free bitcast), the word axis padded
+    to whole 128-word windows when it is wider than one window."""
+    words = jax.lax.bitcast_convert_type(words, jnp.int32)
+    w = words.shape[-1]
+    if w > LANES and w % LANES:
+        words = jnp.pad(words, ((0, 0), (0, LANES - w % LANES)))
+    return words
+
+
+def _bit(ref, idx):
+    """Bit ``idx`` of every row of an (R, W) int32 bitmap ref -> (R, 1) bool.
+
+    Loads the whole block when its rows fit one 128-lane window, else the
+    aligned window holding word ``idx // 32``, and selects the word by lane
+    compare; the caller keeps the row it needs.
+    """
+    w = idx // WORD_BITS
+    if ref.shape[1] <= LANES:
+        start = 0
+        win = ref[...]
+    else:
+        start = pl.multiple_of(w - w % LANES, LANES)
+        win = ref[:, pl.ds(start, LANES)]
+    lane = jax.lax.broadcasted_iota(jnp.int32, win.shape, 1)
+    bits = jnp.where(lane == w - start, (win >> (idx % WORD_BITS)) & 1, 0)
+    return jnp.max(bits, axis=1, keepdims=True) == 1
 
 
 def _constraint_ok(family, meta_val, cons_ref):
-    """Evaluate the candidate's metadata word against the per-query operand."""
+    """Evaluate the candidate's metadata word against every query's operand
+    in the block -> (QB, 1) bool (the caller keeps its own query's row)."""
     if family == "label":
-        lab = meta_val  # int32 label
-        cword = cons_ref[0, lab // WORD_BITS]
-        cbit = (lab % WORD_BITS).astype(jnp.uint32)
-        return ((cword >> cbit) & jnp.uint32(1)) == jnp.uint32(1)
+        return _bit(cons_ref, meta_val)
     if family == "udf":
         # Precompiled predicate table: the metadata word IS the verdict.
-        return meta_val != jnp.int32(0)
-    # "range"
-    return (meta_val >= cons_ref[0, 0]) & (meta_val <= cons_ref[0, 1])
+        return meta_val != 0
+    # "range": lane 0 holds lo, lane 1 hi
+    bounds = cons_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, bounds.shape, 1)
+    lo_ok = jnp.where(bounds <= meta_val, 1, 0)
+    hi_ok = jnp.where(meta_val <= bounds, 1, 0)
+    inside = jnp.where(lane == 0, lo_ok, hi_ok)
+    return jnp.min(inside, axis=1, keepdims=True) == 1
 
 
-def _alive(tomb_ref, cid):
-    """Probe the corpus-wide tombstone bitmap (VMEM-resident, shared by
-    every query): True when the candidate has NOT been deleted/freed."""
-    sid = jnp.maximum(cid, 0)
-    tword = tomb_ref[0, sid // WORD_BITS]
-    tbit = (sid % WORD_BITS).astype(jnp.uint32)
-    return ((tword >> tbit) & jnp.uint32(1)) == jnp.uint32(0)
-
-
-def _cons_spec(family: str, cons: Array):
+def _cons_spec(family: str, qb: int, cons: Array):
     """Per-query operand block — except "udf", whose (1, 1) dummy is pinned
     to block (0, 0) (the predicate travels in the metadata column)."""
     if family == "udf":
-        return pl.BlockSpec((1, cons.shape[1]), lambda i, j, ids_p: (0, 0))
-    return pl.BlockSpec((1, cons.shape[1]), lambda i, j, ids_p: (i, 0))
+        return pl.BlockSpec(cons.shape, lambda i, j, *_: (0, 0))
+    return pl.BlockSpec((qb, cons.shape[1]), lambda i, j, *_: (i, 0))
 
 
-def _make_kernel(family: str, m_blk: int, with_tomb: bool, dma_depth: int):
-    def kernel(
-        ids_ref,  # (B, M) int32, scalar-prefetched (SMEM)
-        q_ref,  # (1, d) query row (VMEM)
-        cons_ref,  # (1, Lw) uint32 words | (1, 2) f32 bounds (VMEM)
-        vis_ref,  # (1, W) uint32 visited words (VMEM)
-        *rest,  # [tomb_ref (1, Wt) u32,] corpus/meta HBM, outs, scratch
-    ):
-        if with_tomb:
-            tomb_ref, *rest = rest
-        else:
-            tomb_ref = None
-        (
-            corpus_hbm,  # (n, d) full corpus (ANY/HBM)
-            meta_hbm,  # (n, 1) label/attr/predicate column (ANY/HBM)
-            dist_ref,  # (1, M_blk) f32 out
-            sat_ref,  # (1, M_blk) int32 out
-            fresh_ref,  # (1, M_blk) int32 out
-            row_buf,  # (dma_depth, 1, d) VMEM scratch — corpus-row ring
-            meta_buf,  # (dma_depth, 1, 1) VMEM scratch — metadata-word ring
-            row_sem,  # (dma_depth,) DMA semaphores
-            meta_sem,  # (dma_depth,) DMA semaphores
-        ) = rest
-        i = pl.program_id(0)
-        jb = pl.program_id(1)
-        base = jb * m_blk
+def _prepare(ids, visited, meta, cons, tomb, family, m_blk, b_pad):
+    """Shared operand layout of both fused kernels: padded ids, their
+    gathered metadata words, int32 bitmaps, the per-query operand."""
+    b, m = ids.shape
+    m_blk = lane_tile(m_blk if m_blk is not None else 128, m)
+    m_pad = _round_up(m, m_blk)
+    ids = ids.astype(jnp.int32)
+    if m_pad != m:
+        ids = jnp.pad(ids, ((0, 0), (0, m_pad - m)), constant_values=-1)
+    ids = pad_rows(ids, b_pad, -1)
+    # One 4-byte word per candidate, gathered by XLA and scalar-prefetched
+    # beside the ids: Mosaic cannot DMA a single word out of the 1-D HBM
+    # column (its tile is 1024 words).
+    meta = meta.reshape(-1)[jnp.maximum(ids, 0)]
+    if family == "range":
+        meta = meta.astype(jnp.float32)
+    elif family == "label":
+        cons = _bitmap(cons)
+    if family != "udf":
+        cons = pad_rows(cons, b_pad)
+    visited = pad_rows(_bitmap(visited), b_pad)
+    tomb = None if tomb is None else _bitmap(tomb.reshape(1, -1))
+    return ids, meta, visited, cons, tomb, m_blk, m_pad
 
-        def row_dma(t, slot):
-            cid = jnp.maximum(ids_ref[i, base + t], 0)
+
+def _fused_loop(family, qb, m_blk, refs, outs, distance, ring=None):
+    """The candidate loop both fused kernels share: visited / constraint /
+    tombstone probes and the three output tiles, carried as values and
+    stored once at the end. ``refs`` = (ids, meta, cons, visited, tomb|None).
+    ``ring`` = (hbm, buf, sem, depth) streams each candidate's (1, ·) row
+    through a ``depth``-slot VMEM ring, overlapping up to ``depth - 1``
+    upcoming copies with the current candidate's work;
+    ``distance(u, slot)`` scores flat candidate ``u`` -> (QB | 1, 1)."""
+    ids_ref, meta_ref, cons_ref, vis_ref, tomb_ref = refs
+    i = pl.program_id(0)
+    jb = pl.program_id(1)
+    n_steps = qb * m_blk
+
+    def at(ref, u):  # flat step -> this candidate's scalar (query-major)
+        return ref[i * qb + u // m_blk, jb * m_blk + u % m_blk]
+
+    if ring is not None:
+        hbm, buf, sem, depth = ring
+
+        def row_dma(u, slot):
+            cid = jnp.maximum(at(ids_ref, u), 0)
             return pltpu.make_async_copy(
-                corpus_hbm.at[pl.ds(cid, 1), :], row_buf.at[slot], row_sem.at[slot]
+                hbm.at[pl.ds(cid, 1), :], buf.at[slot], sem.at[slot]
             )
 
-        def meta_dma(t, slot):
-            cid = jnp.maximum(ids_ref[i, base + t], 0)
-            return pltpu.make_async_copy(
-                meta_hbm.at[pl.ds(cid, 1), :], meta_buf.at[slot], meta_sem.at[slot]
-            )
+        # Warm up the pipeline: the first depth-1 candidates' rows in
+        # flight (the classic double buffer at depth 2).
+        for u0 in range(min(depth - 1, n_steps)):
+            row_dma(u0, u0 % depth).start()
+    sub = jax.lax.broadcasted_iota(jnp.int32, (qb, m_blk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (qb, m_blk), 1)
 
-        # Warm up the pipeline: the first dma_depth-1 candidates' rows +
-        # metadata in flight (the classic double buffer at depth 2).
-        for t0 in range(min(dma_depth - 1, m_blk)):
-            row_dma(t0, t0 % dma_depth).start()
-            meta_dma(t0, t0 % dma_depth).start()
-        q = q_ref[...].astype(jnp.float32)  # (1, d)
+    def body(u, carry):
+        dist, sat, fresh = carry
+        slot = None
+        if ring is not None:
+            slot = u % depth
 
-        def body(t, carry):
-            slot = t % dma_depth
-
-            # Keep dma_depth-1 copies in flight: start candidate
-            # t + dma_depth - 1's DMAs before waiting on candidate t.
-            @pl.when(t + dma_depth - 1 < m_blk)
+            # Keep depth-1 copies in flight: start candidate u + depth - 1's
+            # DMA before waiting on candidate u.
+            @pl.when(u + depth - 1 < n_steps)
             def _():
-                nxt = t + dma_depth - 1
-                row_dma(nxt, nxt % dma_depth).start()
-                meta_dma(nxt, nxt % dma_depth).start()
+                nxt = u + depth - 1
+                row_dma(nxt, nxt % depth).start()
 
-            row_dma(t, slot).wait()
-            meta_dma(t, slot).wait()
+            row_dma(u, slot).wait()
 
-            cid = ids_ref[i, base + t]
-            valid = cid >= 0
+        cid = at(ids_ref, u)
+        valid = cid >= 0
+        sid = jnp.maximum(cid, 0)
+        # The probes below test the candidate against all QB queries of the
+        # block — as cheap as one row, since a (1, ·) value fills a whole
+        # vreg, and free of loads at a dynamic sublane — and `here` keeps
+        # the row of the query the candidate belongs to.
+        d2 = distance(u, slot)
+        unvisited = jnp.logical_not(_bit(vis_ref, sid))
+        ok = _constraint_ok(family, at(meta_ref, u), cons_ref)
+        if tomb_ref is not None:
+            # Tombstone-as-constraint (streaming mutable index): a deleted
+            # slot fails `sat` but stays `fresh`-traversable.
+            ok = ok & jnp.logical_not(_bit(tomb_ref, sid))
 
-            # --- distance: VPU reduction over the freshly landed row -------
-            row = row_buf[slot, 0].astype(jnp.float32)  # (d,)
-            diff = q[0] - row
-            d2 = jnp.sum(diff * diff)
+        here = (sub == u // m_blk) & (lane == u % m_blk)
+        dist = jnp.where(here, jnp.where(valid, d2, jnp.inf), dist)
+        sat = jnp.where(here, (valid & ok).astype(jnp.int32), sat)
+        fresh = jnp.where(here, (valid & unvisited).astype(jnp.int32), fresh)
+        return dist, sat, fresh
 
-            # --- visited probe + constraint on the metadata word -----------
-            unvisited = _unvisited(vis_ref, cid)
-            ok = _constraint_ok(family, meta_buf[slot, 0, 0], cons_ref)
-            if with_tomb:
-                # Tombstone-as-constraint (streaming mutable index): a
-                # deleted slot fails `sat` but stays `fresh`-traversable.
-                ok = ok & _alive(tomb_ref, cid)
+    init = (
+        jnp.zeros((qb, m_blk), jnp.float32),
+        jnp.zeros((qb, m_blk), jnp.int32),
+        jnp.zeros((qb, m_blk), jnp.int32),
+    )
+    for ref, val in zip(outs, jax.lax.fori_loop(0, n_steps, body, init)):
+        ref[...] = val
 
-            dist_ref[0, t] = jnp.where(valid, d2, jnp.inf)
-            sat_ref[0, t] = (valid & ok).astype(jnp.int32)
-            fresh_ref[0, t] = (valid & unvisited).astype(jnp.int32)
-            return carry
 
-        jax.lax.fori_loop(0, m_blk, body, None)
+def _split_refs(refs, with_tomb):
+    """(ids, meta, head, cons, visited, [tomb,] *rest) -> (head, shared
+    probe refs, rest)."""
+    ids_ref, meta_ref, head_ref, cons_ref, vis_ref, *rest = refs
+    tomb_ref, rest = (rest[0], rest[1:]) if with_tomb else (None, rest)
+    return head_ref, (ids_ref, meta_ref, cons_ref, vis_ref, tomb_ref), rest
+
+
+def _make_kernel(family, qb, m_blk, with_tomb, dma_depth):
+    def kernel(*refs):
+        # rest: corpus (n, d) in HBM, 3 (QB, M_blk) outs, row ring, DMA sems.
+        q_ref, probe_refs, rest = _split_refs(refs, with_tomb)
+        corpus_hbm, *outs, row_buf, row_sem = rest
+
+        def distance(u, slot):
+            # VPU reduction of the landed (1, d) row against every query.
+            del u
+            diff = q_ref[...].astype(jnp.float32) - row_buf[slot].astype(
+                jnp.float32
+            )
+            return jnp.sum(diff * diff, axis=1, keepdims=True)
+
+        _fused_loop(
+            family, qb, m_blk, probe_refs, outs, distance,
+            ring=(corpus_hbm, row_buf, row_sem, dma_depth),
+        )
 
     return kernel
+
+
+def _call(kernel, grid_spec, b_pad, m_pad, b, m, interpret, args):
+    dists, sat, fresh = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b_pad, m_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b_pad, m_pad), jnp.int32),
+            jax.ShapeDtypeStruct((b_pad, m_pad), jnp.int32),
+        ],
+        interpret=interpret,
+    )(*args)
+    return dists[:b, :m], sat[:b, :m], fresh[:b, :m]
+
+
+def _grid_spec(family, qb, m_blk, b_pad, m_pad, head_spec, cons, visited,
+               tomb, tail_specs, scratch):
+    out_spec = pl.BlockSpec((qb, m_blk), lambda i, j, *_: (i, j))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # candidate ids + their metadata words
+        grid=(b_pad // qb, m_pad // m_blk),
+        in_specs=[
+            head_spec,
+            _cons_spec(family, qb, cons),
+            pl.BlockSpec((qb, visited.shape[1]), lambda i, j, *_: (i, 0)),
+            # The tombstone bitmap is corpus-wide: ONE block revisited by
+            # every grid step, unlike the per-query operands.
+            *([pl.BlockSpec(tomb.shape, lambda i, j, *_: (0, 0))]
+              if tomb is not None else []),
+            *tail_specs,
+        ],
+        out_specs=[out_spec, out_spec, out_spec],
+        scratch_shapes=scratch,
+    )
 
 
 @functools.partial(
@@ -233,170 +351,65 @@ def fused_expand_kernel(
     if family not in FAMILIES:
         raise ValueError(f"unsupported in-kernel constraint family: {family}")
     b, d = queries.shape
-    _, m = ids.shape
-    m_blk = _resolve_m_blk(m_blk, m)
-    m_pad = _round_up(m, m_blk)
-    ids = ids.astype(jnp.int32)
-    if m_pad != m:
-        ids = jnp.pad(ids, ((0, 0), (0, m_pad - m)), constant_values=-1)
-    meta2d = meta.reshape(-1, 1)
-    if family == "range":
-        meta2d = meta2d.astype(jnp.float32)
-
-    with_tomb = tomb is not None
-    # The tombstone bitmap is corpus-wide: ONE (1, Wt) VMEM block revisited
-    # by every grid step (index map pins it to block (0, 0)), unlike the
-    # per-query operands that follow the batch axis.
-    tomb_specs = (
-        [pl.BlockSpec((1, tomb.shape[0]), lambda i, j, ids_p: (0, 0))]
-        if with_tomb
-        else []
+    m = ids.shape[1]
+    qb = query_block(b)
+    b_pad = _round_up(b, qb)
+    ids, meta, visited, cons, tomb, m_blk, m_pad = _prepare(
+        ids, visited, meta, cons, tomb, family, m_blk, b_pad
     )
-    tomb_args = (tomb.reshape(1, -1),) if with_tomb else ()
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, m_pad // m_blk),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, ids_p: (i, 0)),
-            _cons_spec(family, cons),
-            pl.BlockSpec((1, visited.shape[1]), lambda i, j, ids_p: (i, 0)),
-            *tomb_specs,
-            pl.BlockSpec(memory_space=pltpu.ANY),  # corpus stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # metadata column in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m_blk), lambda i, j, ids_p: (i, j)),
-            pl.BlockSpec((1, m_blk), lambda i, j, ids_p: (i, j)),
-            pl.BlockSpec((1, m_blk), lambda i, j, ids_p: (i, j)),
-        ],
-        scratch_shapes=[
+    grid_spec = _grid_spec(
+        family, qb, m_blk, b_pad, m_pad,
+        pl.BlockSpec((qb, d), lambda i, j, *_: (i, 0)),
+        cons, visited, tomb,
+        [pl.BlockSpec(memory_space=pl.ANY)],  # corpus stays in HBM
+        [
             pltpu.VMEM((dma_depth, 1, d), corpus.dtype),
-            pltpu.VMEM((dma_depth, 1, 1), meta2d.dtype),
-            pltpu.SemaphoreType.DMA((dma_depth,)),
             pltpu.SemaphoreType.DMA((dma_depth,)),
         ],
     )
-    dists, sat, fresh = pl.pallas_call(
-        _make_kernel(family, m_blk, with_tomb, dma_depth),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, m_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b, m_pad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ids, queries, cons, visited, *tomb_args, corpus, meta2d)
-    return dists[:, :m], sat[:, :m], fresh[:, :m]
+    tomb_args = () if tomb is None else (tomb,)
+    return _call(
+        _make_kernel(family, qb, m_blk, tomb is not None, dma_depth),
+        grid_spec, b_pad, m_pad, b, m, interpret,
+        (ids, meta, pad_rows(queries, b_pad), cons, visited, *tomb_args,
+         corpus),
+    )
 
 
-def _make_adc_kernel(
-    family: str,
-    m_blk: int,
-    m_sub: int,
-    n_cent: int,
-    with_tomb: bool,
-    dma_depth: int,
-    lut_tile: int,
-):
+def _make_adc_kernel(family, qb, m_blk, n_cent, with_tomb, lut_tile):
     # lut_tile == 0 (or >= n_cent) means one whole-table slice; either way
-    # the reduction below is per-row-exact, so every tile width is
-    # bit-identical (see module docstring).
+    # the selection below is exact, so every tile width is bit-identical.
     chunk = lut_tile if 0 < lut_tile < n_cent else n_cent
 
-    def kernel(
-        ids_ref,  # (B, M) int32, scalar-prefetched (SMEM)
-        lut_ref,  # (1, m_sub, n_cent) f32 ADC table for this query (VMEM)
-        cons_ref,  # (1, Lw) uint32 words | (1, 2) f32 bounds (VMEM)
-        vis_ref,  # (1, W) uint32 visited words (VMEM)
-        *rest,  # [tomb_ref (1, Wt) u32,] codes/meta HBM, outs, scratch
-    ):
-        if with_tomb:
-            tomb_ref, *rest = rest
-        else:
-            tomb_ref = None
-        (
-            codes_hbm,  # (n, m_sub) int32 full code matrix (ANY/HBM)
-            meta_hbm,  # (n, 1) label/attr/predicate column (ANY/HBM)
-            dist_ref,  # (1, M_blk) f32 out
-            sat_ref,  # (1, M_blk) int32 out
-            fresh_ref,  # (1, M_blk) int32 out
-            code_buf,  # (dma_depth, 1, m_sub) VMEM scratch — code-row ring
-            meta_buf,  # (dma_depth, 1, 1) VMEM scratch — metadata-word ring
-            code_sem,  # (dma_depth,) DMA semaphores
-            meta_sem,  # (dma_depth,) DMA semaphores
-        ) = rest
-        i = pl.program_id(0)
-        jb = pl.program_id(1)
-        base = jb * m_blk
+    def kernel(*refs):
+        # rest: the (QB, M_blk, m_sub) code-row block, 3 (QB, M_blk) outs.
+        lut_ref, probe_refs, (code_ref, *outs) = _split_refs(refs, with_tomb)
 
-        def code_dma(t, slot):
-            cid = jnp.maximum(ids_ref[i, base + t], 0)
-            return pltpu.make_async_copy(
-                codes_hbm.at[pl.ds(cid, 1), :], code_buf.at[slot], code_sem.at[slot]
-            )
-
-        def meta_dma(t, slot):
-            cid = jnp.maximum(ids_ref[i, base + t], 0)
-            return pltpu.make_async_copy(
-                meta_hbm.at[pl.ds(cid, 1), :], meta_buf.at[slot], meta_sem.at[slot]
-            )
-
-        # Warm up the pipeline: the first dma_depth-1 candidates' code rows
-        # + metadata in flight.
-        for t0 in range(min(dma_depth - 1, m_blk)):
-            code_dma(t0, t0 % dma_depth).start()
-            meta_dma(t0, t0 % dma_depth).start()
-        lut = lut_ref[0]  # (m_sub, n_cent) — the query's ADC table, VMEM
-        # One-hot centroid selector: dynamic-gather-free LUT lookup (TPU
-        # needs >= 2D iota; compare-select-reduce is plain VPU work).
-        cent = jax.lax.broadcasted_iota(jnp.int32, (m_sub, n_cent), 1)
-
-        def body(t, carry):
-            slot = t % dma_depth
-
-            # Keep dma_depth-1 copies in flight: start candidate
-            # t + dma_depth - 1's DMAs before waiting on candidate t.
-            @pl.when(t + dma_depth - 1 < m_blk)
-            def _():
-                nxt = t + dma_depth - 1
-                code_dma(nxt, nxt % dma_depth).start()
-                meta_dma(nxt, nxt % dma_depth).start()
-
-            code_dma(t, slot).wait()
-            meta_dma(t, slot).wait()
-
-            cid = ids_ref[i, base + t]
-            valid = cid >= 0
-
-            # --- ADC distance: per-subspace LUT entry sum ------------------
-            # Sliced over `chunk` centroid columns; each row slice selects
-            # at most one non-zero, so vals[s] is EXACTLY lut[s, crow[s]]
-            # (+0.0 folds are exact) and the final (m_sub,) reduction is
-            # identical for every tile width.
-            crow = code_buf[slot, 0]  # (m_sub,) int32 centroid ids
-            vals = jnp.zeros((m_sub,), jnp.float32)
+        def distance(u, slot):
+            del slot
+            qi, t = u // m_blk, u % m_blk
+            # Candidate t's (1, m_sub) centroid ids, picked by sublane
+            # compare from its query's code rows.
+            rows = code_ref[qi]
+            sub = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+            crow = jnp.max(jnp.where(sub == t, rows, 0), axis=0, keepdims=True)
+            # Sliced over `chunk` centroid rows of the transposed
+            # (n_cent, m_sub) table; each subspace column holds exactly one
+            # selected entry, so a max over -inf padding makes vals[s]
+            # EXACTLY lut[s, crow[s]] for every tile width (max is exact
+            # and associative, so no compiler reassociation can change it).
+            vals = jnp.full(crow.shape, -jnp.inf, jnp.float32)
             for c0 in range(0, n_cent, chunk):
                 c1 = min(c0 + chunk, n_cent)
-                sel = cent[:, c0:c1] == crow[:, None]
-                vals = vals + jnp.sum(
-                    jnp.where(sel, lut[:, c0:c1], 0.0), axis=1
-                )
-            d2 = jnp.sum(vals)
+                lut = lut_ref[qi, pl.ds(c0, c1 - c0), :]
+                cent = c0 + jax.lax.broadcasted_iota(jnp.int32, lut.shape, 0)
+                vals = jnp.maximum(vals, jnp.max(
+                    jnp.where(cent == crow, lut, -jnp.inf), axis=0,
+                    keepdims=True,
+                ))
+            return jnp.sum(vals, axis=1, keepdims=True)
 
-            # --- visited probe + constraint on the metadata word -----------
-            unvisited = _unvisited(vis_ref, cid)
-            ok = _constraint_ok(family, meta_buf[slot, 0, 0], cons_ref)
-            if with_tomb:
-                # Tombstone-as-constraint (streaming mutable index): a
-                # deleted slot fails `sat` but stays `fresh`-traversable.
-                ok = ok & _alive(tomb_ref, cid)
-
-            dist_ref[0, t] = jnp.where(valid, d2, jnp.inf)
-            sat_ref[0, t] = (valid & ok).astype(jnp.int32)
-            fresh_ref[0, t] = (valid & unvisited).astype(jnp.int32)
-            return carry
-
-        jax.lax.fori_loop(0, m_blk, body, None)
+        _fused_loop(family, qb, m_blk, probe_refs, outs, distance)
 
     return kernel
 
@@ -422,62 +435,36 @@ def fused_expand_adc_kernel(
 ) -> tuple[Array, Array, Array]:
     """(B, m_sub, n_cent) f32 LUT, (n, m_sub) i32 codes, (B, M) i32 ids,
     (B, W) u32 visited, (n,|n,1) meta, (B, ·) cons [, (Wt,) u32 tombstones]
-    -> ((B, M) f32 ADC dists, (B, M) i32 satisfied, (B, M) i32 fresh)."""
+    -> ((B, M) f32 ADC dists, (B, M) i32 satisfied, (B, M) i32 fresh).
+
+    ``dma_depth`` is accepted for the shared tuning lattice and unused: the
+    candidates' code rows are gathered by XLA (one (n, m_sub) row is
+    narrower than the 128-lane tile a DMA may slice) and arrive as a
+    (QB, M_blk, m_sub) block."""
+    del dma_depth
     if family not in FAMILIES:
         raise ValueError(f"unsupported in-kernel constraint family: {family}")
     b, m_sub, n_cent = lut.shape
-    _, m = ids.shape
-    m_blk = _resolve_m_blk(m_blk, m)
-    m_pad = _round_up(m, m_blk)
-    ids = ids.astype(jnp.int32)
-    if m_pad != m:
-        ids = jnp.pad(ids, ((0, 0), (0, m_pad - m)), constant_values=-1)
-    meta2d = meta.reshape(-1, 1)
-    if family == "range":
-        meta2d = meta2d.astype(jnp.float32)
-    codes = codes.astype(jnp.int32)
-    lut = lut.astype(jnp.float32)
-
-    with_tomb = tomb is not None
-    tomb_specs = (
-        [pl.BlockSpec((1, tomb.shape[0]), lambda i, j, ids_p: (0, 0))]
-        if with_tomb
-        else []
+    m = ids.shape[1]
+    qb = query_block(b)
+    b_pad = _round_up(b, qb)
+    ids, meta, visited, cons, tomb, m_blk, m_pad = _prepare(
+        ids, visited, meta, cons, tomb, family, m_blk, b_pad
     )
-    tomb_args = (tomb.reshape(1, -1),) if with_tomb else ()
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, m_pad // m_blk),
-        in_specs=[
-            pl.BlockSpec((1, m_sub, n_cent), lambda i, j, ids_p: (i, 0, 0)),
-            _cons_spec(family, cons),
-            pl.BlockSpec((1, visited.shape[1]), lambda i, j, ids_p: (i, 0)),
-            *tomb_specs,
-            pl.BlockSpec(memory_space=pltpu.ANY),  # code matrix stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # metadata column in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, m_blk), lambda i, j, ids_p: (i, j)),
-            pl.BlockSpec((1, m_blk), lambda i, j, ids_p: (i, j)),
-            pl.BlockSpec((1, m_blk), lambda i, j, ids_p: (i, j)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((dma_depth, 1, m_sub), jnp.int32),
-            pltpu.VMEM((dma_depth, 1, 1), meta2d.dtype),
-            pltpu.SemaphoreType.DMA((dma_depth,)),
-            pltpu.SemaphoreType.DMA((dma_depth,)),
-        ],
+    crows = codes.astype(jnp.int32)[jnp.maximum(ids, 0)]  # (B, M, m_sub)
+    # (B, n_cent, m_sub): the code row then broadcasts down the sublanes.
+    lut_t = pad_rows(jnp.swapaxes(lut.astype(jnp.float32), 1, 2), b_pad)
+    grid_spec = _grid_spec(
+        family, qb, m_blk, b_pad, m_pad,
+        pl.BlockSpec((qb, n_cent, m_sub), lambda i, j, *_: (i, 0, 0)),
+        cons, visited, tomb,
+        [pl.BlockSpec((qb, m_blk, m_sub), lambda i, j, *_: (i, j, 0))],
+        [],
     )
-    dists, sat, fresh = pl.pallas_call(
-        _make_adc_kernel(
-            family, m_blk, m_sub, n_cent, with_tomb, dma_depth, lut_tile
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, m_pad), jnp.int32),
-            jax.ShapeDtypeStruct((b, m_pad), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ids, lut, cons, visited, *tomb_args, codes, meta2d)
-    return dists[:, :m], sat[:, :m], fresh[:, :m]
+    tomb_args = () if tomb is None else (tomb,)
+    return _call(
+        _make_adc_kernel(family, qb, m_blk, n_cent, tomb is not None,
+                         lut_tile),
+        grid_spec, b_pad, m_pad, b, m, interpret,
+        (ids, meta, lut_t, cons, visited, *tomb_args, crows),
+    )

@@ -5,17 +5,15 @@ computes D[b, m] = ||Q[b] - corpus[IDS[b, m]]||^2 without materializing the
 (B, M, d) gathered tensor in HBM.
 
 TPU mapping: the id matrix is *scalar-prefetched* (SMEM) and drives manual
-pipelined row DMAs over a ``(B, M / m_blk)`` grid with lane-aligned
-``(1, m_blk)`` output tiles — the same layout as the fused-expansion
-kernels (kernels/fused_expand), minus their metadata word and constraint /
-visited probes. Each grid step streams ``m_blk`` corpus rows through a
-``dma_depth``-slot VMEM ring buffer, overlapping upcoming row copies with
-the current row's VPU distance reduction. (The original one-row-per-grid-
-step layout — (B, M) grid, (1, 1) output blocks, BlockSpec-index-map
-gather — left the block shape unsearchable; this form exposes the same
-``m_blk``/``dma_depth`` lattice the autotuner sweeps, DESIGN.md §11.)
-This kernel is HBM-bandwidth-bound by construction — see EXPERIMENTS.md
-§Roofline.
+pipelined row DMAs over a ``(B / QB, M / m_blk)`` grid with ``(QB, m_blk)``
+output tiles, QB = ``min(8, B)`` queries per step — the same layout as the
+fused-expansion kernels (kernels/fused_expand), minus their metadata word
+and constraint / visited probes. Each grid step streams its ``QB * m_blk``
+corpus rows through a ``dma_depth``-slot VMEM ring buffer, overlapping
+upcoming row copies with the current row's VPU distance reduction, and
+carries its output tile as a value stored once (VMEM takes no scalar
+stores). This kernel is HBM-bandwidth-bound by construction — see
+EXPERIMENTS.md §Roofline.
 
 Padding ids (< 0) are redirected to row 0 and reported as +inf.
 """
@@ -28,6 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.fused_expand.fused_expand import pad_rows, query_block
+from repro.tune.config import lane_tile
+
 Array = jax.Array
 
 
@@ -35,46 +36,55 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
-def _make_kernel(m_blk: int, dma_depth: int):
+def _make_kernel(qb: int, m_blk: int, dma_depth: int):
+    n_steps = qb * m_blk
+
     def kernel(
         ids_ref,  # (B, M) int32, scalar-prefetched (SMEM)
-        q_ref,  # (1, d) query row (VMEM)
+        q_ref,  # (QB, d) query rows (VMEM)
         corpus_hbm,  # (n, d) full corpus (ANY/HBM)
-        out_ref,  # (1, m_blk) f32 out
+        out_ref,  # (QB, m_blk) f32 out
         row_buf,  # (dma_depth, 1, d) VMEM scratch — corpus-row ring
         row_sem,  # (dma_depth,) DMA semaphores
     ):
         i = pl.program_id(0)
         jb = pl.program_id(1)
-        base = jb * m_blk
 
-        def row_dma(t, slot):
-            cid = jnp.maximum(ids_ref[i, base + t], 0)
+        def cand(u):  # flat step -> candidate id (query-major)
+            return ids_ref[i * qb + u // m_blk, jb * m_blk + u % m_blk]
+
+        def row_dma(u, slot):
+            cid = jnp.maximum(cand(u), 0)
             return pltpu.make_async_copy(
                 corpus_hbm.at[pl.ds(cid, 1), :], row_buf.at[slot], row_sem.at[slot]
             )
 
-        for t0 in range(min(dma_depth - 1, m_blk)):
-            row_dma(t0, t0 % dma_depth).start()
-        q = q_ref[...].astype(jnp.float32)  # (1, d)
+        for u0 in range(min(dma_depth - 1, n_steps)):
+            row_dma(u0, u0 % dma_depth).start()
+        q = q_ref[...].astype(jnp.float32)  # (QB, d)
+        sub = jax.lax.broadcasted_iota(jnp.int32, (qb, m_blk), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (qb, m_blk), 1)
 
-        def body(t, carry):
-            slot = t % dma_depth
+        def body(u, out):
+            slot = u % dma_depth
 
-            @pl.when(t + dma_depth - 1 < m_blk)
+            @pl.when(u + dma_depth - 1 < n_steps)
             def _():
-                nxt = t + dma_depth - 1
+                nxt = u + dma_depth - 1
                 row_dma(nxt, nxt % dma_depth).start()
 
-            row_dma(t, slot).wait()
-            row = row_buf[slot, 0].astype(jnp.float32)  # (d,)
-            diff = q[0] - row
-            d2 = jnp.sum(diff * diff)
-            pad = ids_ref[i, base + t] < 0
-            out_ref[0, t] = jnp.where(pad, jnp.inf, d2)
-            return carry
+            row_dma(u, slot).wait()
+            # Scored against every query of the block (one vreg either
+            # way); `here` keeps the row of the query the candidate
+            # belongs to.
+            diff = q - row_buf[slot].astype(jnp.float32)
+            d2 = jnp.sum(diff * diff, axis=1, keepdims=True)  # (QB, 1)
+            here = (sub == u // m_blk) & (lane == u % m_blk)
+            return jnp.where(here, jnp.where(cand(u) < 0, jnp.inf, d2), out)
 
-        jax.lax.fori_loop(0, m_blk, body, None)
+        out_ref[...] = jax.lax.fori_loop(
+            0, n_steps, body, jnp.zeros((qb, m_blk), jnp.float32)
+        )
 
     return kernel
 
@@ -94,30 +104,32 @@ def gather_distance_kernel(
     """(B, d), (n, d), (B, M) int32 -> (B, M) f32 squared distances."""
     b, d = queries.shape
     _, m = ids.shape
-    # m_blk is a cap on the lane-aligned output-tile width: small neighbor
-    # lists collapse to one tile (see repro.tune.config.effective_m_blk).
-    m_blk = min(m_blk if m_blk is not None else 128, _round_up(m, 8))
+    # m_blk is a cap on the output-tile width: small neighbor lists
+    # collapse to one tile (see repro.tune.config.lane_tile).
+    m_blk = lane_tile(m_blk if m_blk is not None else 128, m)
     m_pad = _round_up(m, m_blk)
+    qb = query_block(b)
+    b_pad = _round_up(b, qb)
     ids = ids.astype(jnp.int32)
     if m_pad != m:
         ids = jnp.pad(ids, ((0, 0), (0, m_pad - m)), constant_values=-1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, m_pad // m_blk),
+        grid=(b_pad // qb, m_pad // m_blk),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, ids_pref: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # corpus stays in HBM
+            pl.BlockSpec((qb, d), lambda i, j, ids_pref: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # corpus stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, m_blk), lambda i, j, ids_pref: (i, j)),
+        out_specs=pl.BlockSpec((qb, m_blk), lambda i, j, ids_pref: (i, j)),
         scratch_shapes=[
             pltpu.VMEM((dma_depth, 1, d), corpus.dtype),
             pltpu.SemaphoreType.DMA((dma_depth,)),
         ],
     )
     out = pl.pallas_call(
-        _make_kernel(m_blk, dma_depth),
+        _make_kernel(qb, m_blk, dma_depth),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, m_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b_pad, m_pad), jnp.float32),
         interpret=interpret,
-    )(ids, queries, corpus)
-    return out[:, :m]
+    )(pad_rows(ids, b_pad, -1), pad_rows(queries, b_pad), corpus)
+    return out[:b, :m]

@@ -4,11 +4,15 @@ Given per-query LUTs (B, m_sub, n_cent) of subspace distances and the code
 matrix (N, m_sub), computes ADC[b, v] = sum_s LUT[b, s, codes[v, s]].
 
 TPU mapping: VMEM-gather is awkward on the VPU, so the lookup is recast as a
-one-hot × LUT matmul that rides the MXU: each (bn,)-row code slice becomes a
-(bn, m_sub·n_cent) one-hot block contracted with the flattened LUT row. The
-one-hot block lives only in VMEM (bn=256, m_sub=16, n_cent=256 → 4 MB f32)
-and the scan streams code blocks from HBM — memory-bound at ~m_sub bytes per
-corpus vector, the same arithmetic the paper's CPU baseline does per scan.
+one-hot × LUT matmul that rides the MXU. A grid step owns QB = min(8, B)
+queries and a (bn,)-row code slice; per subspace the slice becomes a
+(bn, n_cent) one-hot block contracted with the queries' (QB, n_cent) LUT
+rows, accumulated into the (QB, bn) output tile. The one-hot block lives
+only in VMEM (bn=256, n_cent=256 → 256 KB f32, well inside the scoped
+limit) and the scan streams code blocks from HBM — memory-bound at ~m_sub
+words per corpus vector, the same arithmetic the paper's CPU baseline does
+per scan. ``bn`` is a cap resolved like the fused kernels' tile width
+(``repro.tune.config.lane_tile``).
 """
 from __future__ import annotations
 
@@ -18,23 +22,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.fused_expand.fused_expand import pad_rows, query_block
+from repro.tune.config import lane_tile
+
 Array = jax.Array
 
 
-def _kernel(lut_ref, codes_ref, out_ref, *, n_cent: int):
-    lut = lut_ref[...].astype(jnp.float32)  # (1, m_sub, n_cent)
+def _kernel(lut_ref, codes_ref, out_ref, *, m_sub: int, n_cent: int):
     codes = codes_ref[...]  # (bn, m_sub) int32
-    bn, m_sub = codes.shape
-    # one-hot over centroids, flattened over (m_sub, n_cent) -> MXU matvec.
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, m_sub, n_cent), 2)
-    onehot = (iota == codes[:, :, None]).astype(jnp.float32)
-    flat = onehot.reshape(bn, m_sub * n_cent)
-    out_ref[...] = jax.lax.dot_general(
-        flat,
-        lut.reshape(1, m_sub * n_cent),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).T  # (1, bn)
+    cent = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], n_cent), 1)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)  # (QB, bn)
+    for s in range(m_sub):
+        # One subspace at a time: a (bn, n_cent) one-hot block contracted
+        # with the block's (QB, n_cent) LUT rows on the MXU. HIGHEST
+        # precision keeps the f32 LUT entries exact through the multiply.
+        onehot = (cent == codes[:, s:s + 1]).astype(jnp.float32)
+        acc = acc + jax.lax.dot_general(
+            lut_ref[s].astype(jnp.float32),
+            onehot,
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -44,20 +54,25 @@ def pq_adc_kernel(
     """(B, m_sub, n_cent) x (N, m_sub) -> (B, N) f32 ADC distances."""
     b, m_sub, n_cent = lut.shape
     n, m2 = codes.shape
-    assert m_sub == m2
-    bn = min(bn, n)
-    pad = (-n) % bn
-    cp = jnp.pad(codes, ((0, pad), (0, 0)))
-    grid = (b, (n + pad) // bn)
+    if m_sub != m2:
+        raise ValueError(f"LUT has {m_sub} subspaces, codes have {m2}")
+    bn = lane_tile(bn, n)
+    n_pad = -(-n // bn) * bn
+    qb = query_block(b)
+    b_pad = -(-b // qb) * qb
+    cp = jnp.pad(codes.astype(jnp.int32), ((0, n_pad - n), (0, 0)))
+    # (m_sub, B, n_cent): the kernel takes one subspace's (QB, n_cent) rows
+    # per step along the leading axis.
+    lut_s = jnp.swapaxes(pad_rows(lut, b_pad), 0, 1)
     out = pl.pallas_call(
-        functools.partial(_kernel, n_cent=n_cent),
-        grid=grid,
+        functools.partial(_kernel, m_sub=m_sub, n_cent=n_cent),
+        grid=(b_pad // qb, n_pad // bn),
         in_specs=[
-            pl.BlockSpec((1, m_sub, n_cent), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((m_sub, qb, n_cent), lambda i, j: (0, i, 0)),
             pl.BlockSpec((bn, m_sub), lambda i, j: (j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, n + pad), jnp.float32),
+        out_specs=pl.BlockSpec((qb, bn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((b_pad, n_pad), jnp.float32),
         interpret=interpret,
-    )(lut, cp.astype(jnp.int32))
-    return out[:, :n]
+    )(lut_s, cp)
+    return out[:b, :n]

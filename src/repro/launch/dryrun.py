@@ -20,7 +20,6 @@ import time  # noqa: E402
 import traceback  # noqa: E402
 
 import jax  # noqa: E402
-from repro.common.compat import cost_analysis_dict, set_mesh  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 
 from repro.archs.base import get_arch  # noqa: E402
@@ -41,7 +40,7 @@ def dryrun_cell(arch_name: str, shape: str, *, multi_pod: bool, out_dir: str | N
         is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
     )
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             cell.fn,
             in_shardings=in_shardings,
@@ -53,7 +52,7 @@ def dryrun_cell(arch_name: str, shape: str, *, multi_pod: bool, out_dir: str | N
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes_from_hlo(hlo)
 

@@ -32,6 +32,7 @@ import threading
 
 import jax
 
+from repro.common.jaxcache import enable_compile_cache
 from repro.data.synthetic import make_labeled_corpus
 from repro.graph.index import build_index, build_partitioned_index
 from repro.serving import (
@@ -73,7 +74,8 @@ def build_runtime(args, corpus, clock, prebuilt_graph=None, replica_id=None):
 
         print("building streaming index (slot pool)...")
         graph = prebuilt_graph if prebuilt_graph is not None else build_index(
-            jax.random.PRNGKey(1), corpus, degree=16, sample_size=512
+            jax.random.PRNGKey(1), corpus, degree=args.degree,
+            sample_size=args.sample_size,
         )
         index = StreamingIndex.from_static(
             corpus, graph, ef_insert=args.base_ef
@@ -92,7 +94,7 @@ def build_runtime(args, corpus, clock, prebuilt_graph=None, replica_id=None):
         print(f"mesh: {dict(mesh.shape)}")
         print("building partitioned index...")
         corpus_p, graph_p = build_partitioned_index(
-            jax.random.PRNGKey(1), corpus, n_shards=model, degree=16,
+            jax.random.PRNGKey(1), corpus, n_shards=model, degree=args.degree,
             sample_size_per_shard=128,
         )
         corpus_s, graph_s = shard_corpus_for_mesh(corpus_p, graph_p, mesh)
@@ -104,7 +106,8 @@ def build_runtime(args, corpus, clock, prebuilt_graph=None, replica_id=None):
         else:
             print("building index...")
             graph = build_index(
-                jax.random.PRNGKey(1), corpus, degree=16, sample_size=512
+                jax.random.PRNGKey(1), corpus, degree=args.degree,
+                sample_size=args.sample_size,
             )
         pq_index = train_pq(corpus.vectors) if args.approx == "pq" else None
         executor = LocalExecutor(corpus, graph, pq_index)
@@ -181,11 +184,27 @@ def build_runtime(args, corpus, clock, prebuilt_graph=None, replica_id=None):
     return runtime
 
 
-def main():
+def make_corpus(args):
+    """The served corpus: ``args.n`` clustered ``args.d``-dim vectors with
+    k-means labels (the paper's protocol) and two uniform attribute columns
+    for range constraints."""
+    corpus = make_labeled_corpus(
+        jax.random.PRNGKey(0), n=args.n, d=args.d, n_labels=args.labels
+    )
+    return corpus.replace(
+        attrs=jax.random.uniform(jax.random.PRNGKey(5), (args.n, 2))
+    )
+
+
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20_000)
     ap.add_argument("--d", type=int, default=32)
     ap.add_argument("--labels", type=int, default=10)
+    ap.add_argument("--degree", type=int, default=16,
+                    help="proximity-graph out-degree")
+    ap.add_argument("--sample-size", type=int, default=512,
+                    help="pre-drawn start-point sample (AIRSHIP-Start)")
     ap.add_argument("--requests", type=int, default=256)
     ap.add_argument("--rate", type=float, default=2000.0,
                     help="Poisson arrival rate (requests/s of virtual time)")
@@ -272,7 +291,11 @@ def main():
         "records with req_id/batch_id/epoch) buffered in a bounded ring "
         "and flushed to PATH at shutdown",
     )
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = make_parser().parse_args()
     if args.replicas < 1:
         raise SystemExit("--replicas must be >= 1")
     if args.replicas > 1 and args.serve_http is None:
@@ -282,12 +305,8 @@ def main():
         raise SystemExit("--replicas replicates the local executor; the "
                          "mesh path is single-tier (drop --distributed)")
 
-    corpus = make_labeled_corpus(
-        jax.random.PRNGKey(0), n=args.n, d=args.d, n_labels=args.labels
-    )
-    corpus = corpus.replace(
-        attrs=jax.random.uniform(jax.random.PRNGKey(5), (args.n, 2))
-    )
+    enable_compile_cache()
+    corpus = make_corpus(args)
 
     # HTTP mode serves real clients, so it runs on the wall clock; replay
     # mode keeps the deterministic virtual timeline.
@@ -302,7 +321,8 @@ def main():
 
         print(f"building index (shared across {args.replicas} replicas)...")
         shared_graph = build_index(
-            jax.random.PRNGKey(1), corpus, degree=16, sample_size=512
+            jax.random.PRNGKey(1), corpus, degree=args.degree,
+            sample_size=args.sample_size,
         )
         runtime = ReplicaSet(
             [

@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.common.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.meshinfo import MeshInfo
@@ -112,9 +111,10 @@ def dst_partitioned_energy(
 
     feat_key = "node_feat" if cfg.d_feat else "species"
     edge_spec = P(axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mi.mesh,
+        check_vma=False,
         in_specs=(P(), P(), edge_spec, edge_spec),
         out_specs=P(),
     )

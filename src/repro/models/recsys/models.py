@@ -16,7 +16,6 @@ from typing import Any, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.common.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.meshinfo import MeshInfo
@@ -409,9 +408,10 @@ def two_tower_score_candidates(
             )
             return t2, i2
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mi.mesh,
+            check_vma=False,
             in_specs=(P(bspec, None), P(tp, None)),
             out_specs=(P(bspec, None), P(bspec, None)),
         )(u, cand)

@@ -11,7 +11,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.common.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.meshinfo import MeshInfo
@@ -512,9 +511,10 @@ def _gqa_decode_sharded(ap, cfg, mi, h, k_cache, v_cache, pos, seq_axis):
                 seq_axis=seq_axis, shard_idx=jax.lax.axis_index(seq_axis),
             )
 
-        out, k_c, v_c = shard_map(
+        out, k_c, v_c = jax.shard_map(
             inner,
             mesh=mi.mesh,
+            check_vma=False,
             in_specs=(
                 P(bspec, None, None),
                 P(bspec, seq_axis, None, None),
@@ -547,9 +547,10 @@ def _mla_decode_sharded(ap, cfg, mi, h, c_cache, pos, seq_axis):
             seq_axis=seq_axis, shard_idx=jax.lax.axis_index(seq_axis),
         )
 
-    out, c_c = shard_map(
+    out, c_c = jax.shard_map(
         inner,
         mesh=mi.mesh,
+        check_vma=False,
         in_specs=(P(bspec, None), P(bspec, seq_axis, None)),
         out_specs=(P(bspec, None), P(bspec, seq_axis, None)),
     )(h, c_cache)

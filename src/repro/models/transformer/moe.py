@@ -20,7 +20,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.common.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.meshinfo import MeshInfo
@@ -138,11 +137,12 @@ def moe_ffn(p: Params, cfg, mi: MeshInfo, x: Array) -> Array:
     tp = mi.tp_axis
     e = cfg.n_experts
     if mi.tp_size > 1 and e % mi.tp_size == 0:
-        local = shard_map(
+        local = jax.shard_map(
             lambda xs, ps, w1, w3, w2: _moe_local(
                 xs, ps, w1, w3, w2, cfg=cfg, tp_axis=tp
             ),
             mesh=mi.mesh,
+            check_vma=False,
             in_specs=(
                 P(dp, None, None),
                 P(dp, None, None),

@@ -268,14 +268,38 @@ def recsys_terms(cfg, batch: int, chips: int, kind: str, n_candidates: int = 0):
 # The block-shape autotuner (repro.tune) prunes lattice configs the model
 # predicts are memory-dominated-worse before spending wall-clock on them,
 # and the regression gate anchors measured kernel time against the same
-# bound. Two platforms: "tpu" uses the chip constants above; anything else
-# is treated as a host (CPU jnp/interpret) with the sustained-DRAM numbers
-# below — deliberately round figures, because the gate compares *fractions
-# of the bound across runs on the same platform*, where the constant
-# cancels, not absolute MFU claims.
+# bound. On a TPU the bound uses that chip's published peaks, looked up by
+# the device kind JAX reports — a kind missing from TPU_PEAKS is an error,
+# never a silent v5e. Any other platform is treated as a host (CPU
+# jnp/interpret) with the sustained-DRAM numbers below — deliberately round
+# figures, because the gate compares *fractions of the bound across runs on
+# the same platform*, where the constant cancels, not absolute MFU claims.
 HOST_BW = 20e9  # B/s sustained single-socket DRAM stream
 HOST_FLOPS = 100e9  # f32 FLOP/s, one core + modest SIMD (pytest/CI class)
 VMEM_BYTES = 64 * 1024 * 1024  # per-core VMEM budget we allow a config
+# (FLOP/s, HBM bytes/s) per chip, keyed by jax's ``device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s).
+TPU_PEAKS = {"TPU v5 lite": (PEAK_FLOPS, HBM_BW)}
+
+
+def chip_peaks(platform: str, device_kind: str | None = None) -> tuple:
+    """(FLOP/s, bytes/s) that bound a kernel on ``platform``.
+
+    On "tpu" these are the peaks of ``device_kind`` (default: the first
+    device's); a kind not in ``TPU_PEAKS`` raises ValueError.
+    """
+    if platform != "tpu":
+        return HOST_FLOPS, HOST_BW
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in TPU_PEAKS:
+        raise ValueError(
+            f"no roofline peaks for TPU device kind {device_kind!r} "
+            f"(known: {sorted(TPU_PEAKS)})"
+        )
+    return TPU_PEAKS[device_kind]
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -299,10 +323,10 @@ class KernelRoofline:
     vmem_bytes: float
 
     def t_compute(self, platform: str = "tpu") -> float:
-        return self.flops / (PEAK_FLOPS if platform == "tpu" else HOST_FLOPS)
+        return self.flops / chip_peaks(platform)[0]
 
     def t_memory(self, platform: str = "tpu") -> float:
-        return self.hbm_bytes / (HBM_BW if platform == "tpu" else HOST_BW)
+        return self.hbm_bytes / chip_peaks(platform)[1]
 
     def time_bound(self, platform: str = "tpu") -> float:
         return max(self.t_compute(platform), self.t_memory(platform))
@@ -326,10 +350,12 @@ def kernel_roofline(
     gather_distance, the subquantizer count m_sub for fused_adc / pq_adc
     (for pq_adc, ``m`` is the corpus row count the scan covers). Mirrors
     the kernels' own padding arithmetic: effective tile =
-    min(m_blk, round_up(m, 8)), m_pad = round_up(m, tile) — the term that
+    ``lane_tile(m_blk, m)``, m_pad = round_up(m, tile) — the term that
     makes one m_blk beat another at fixed work.
     """
-    eff = min(config.m_blk, _round_up(max(m, 1), 8))
+    from repro.tune.config import lane_tile
+
+    eff = lane_tile(config.m_blk, max(m, 1))
     m_pad = _round_up(max(m, 1), eff)
     row = 4.0 * d  # f32 vector row / int32 code row
     if kernel in ("fused_exact", "fused_adc"):

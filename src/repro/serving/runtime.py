@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import set_mesh
 from repro.core import build_context, make_distributed_search, search_with_context
 from repro.core.constraints import WORD_BITS, LabelSetConstraint, RangeConstraint
 from repro.core.estimator import SelectivityEstimator
@@ -254,7 +253,7 @@ class DistributedExecutor:
         search = make_distributed_search(self.mesh, params, constraint_type=ctype)
 
         def fn(queries: Array, constraint) -> SearchResult:
-            with set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 return search(
                     self.corpus_s, self.graph_s, queries, constraint, self.pq_index
                 )

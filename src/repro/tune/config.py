@@ -9,22 +9,22 @@ harness (tune/sweep.py) can search them and the committed tuning table
 
 Semantics — chosen so every config is numerically invisible:
 
-  * ``m_blk`` is a CAP on the (1, m_blk) output-tile width, resolved per
-    call as ``min(m_blk, round_up(m, 8))`` (``effective_m_blk``): small
-    candidate batches always collapse to one lane-aligned tile, exactly
-    like the pre-autotuner default, and distances are computed per
-    candidate regardless of tiling — every ``m_blk`` yields identical
-    bits (tests/test_tune.py property tests).
+  * ``m_blk`` is a CAP on the output-tile width, resolved per call by
+    ``lane_tile``: small candidate batches always collapse to one tile of
+    ``round_up(m, 8)``, exactly like the pre-autotuner default; larger
+    ones split into tiles of the cap rounded up to whole 128-lane tiles
+    (the only other width the TPU compiler accepts). Distances are
+    computed per candidate regardless of tiling — every ``m_blk`` yields
+    identical bits (tests/test_tune.py property tests).
   * ``dma_depth`` is the candidate-row DMA pipeline depth (ring-buffer
     slots). 2 is the classic double buffer; 3–4 keep more row copies in
     flight to ride out HBM latency jitter at the cost of VMEM. Scheduling
     only — never touches values.
   * ``lut_tile`` (fused ADC kernel only) chunks the per-probe one-hot
-    LUT reduction over ``n_cent`` in ``lut_tile``-column slices; 0 means
-    the whole table at once. Each code row selects exactly ONE column per
-    subspace, so per-row chunk sums reduce at most one non-zero (exact
-    +0.0 padding — LUT entries are squared distances, never -0.0) and
-    tiling is bit-invariant by construction (kernels/fused_expand).
+    LUT selection over ``n_cent`` in ``lut_tile``-row slices; 0 means
+    the whole table at once. Each code row selects exactly ONE entry per
+    subspace, picked by an exact max over -inf padding, so tiling is
+    bit-invariant by construction (kernels/fused_expand).
 
 The declared lattice is the ONLY space the sweep searches and the only
 space ``table.json`` may contain (CI validates membership — see
@@ -88,9 +88,20 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def lane_tile(cap: int, m: int) -> int:
+    """Output-tile width for ``m`` candidates under an ``m_blk`` cap.
+
+    One tile of ``round_up(m, 8)`` when the cap covers ``m`` (a block equal
+    to the whole padded axis); otherwise the cap rounded up to whole
+    128-lane tiles, the only other block width the TPU compiler accepts.
+    """
+    tile = min(cap, _round_up(m, 8))
+    return tile if tile >= m else _round_up(tile, 128)
+
+
 def effective_m_blk(config: KernelConfig, m: int) -> int:
     """Resolve the m_blk cap against an actual candidate count."""
-    return min(config.m_blk, _round_up(m, 8))
+    return lane_tile(config.m_blk, m)
 
 
 def validate_config(kernel: str, config: KernelConfig) -> None:

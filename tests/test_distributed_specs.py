@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.common.compat import set_mesh
 from repro.core import (
     RangeConstraint,
     SearchParams,
@@ -69,7 +68,7 @@ def test_range_constraint_through_sharded_path(world):
         lo=jnp.full((b,), 0.3), hi=jnp.full((b,), 0.9), col=jnp.int32(0)
     )
     search = make_distributed_search(mesh, PARAMS, constraint_type=RangeConstraint)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         res = search(corpus_s, graph_s, queries, cons)
     ids = np.asarray(res.ids)
     vals = np.asarray(corpus_p.attrs)[np.maximum(ids, 0), 0]
@@ -93,7 +92,7 @@ def test_pq_backend_payload_derived_from_params(world):
     cons = equal_constraint(qlab, 5)
     pq = pq_train(jax.random.PRNGKey(11), corpus_p.vectors, m_sub=4, n_cent=16)
     params_pq = dataclasses.replace(PARAMS, approx="pq")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         res = make_distributed_search(mesh, params_pq)(
             corpus_s, graph_s, queries, cons, pq
         )
@@ -116,7 +115,7 @@ def test_uniform_pq_index_signature(world):
     corpus_s, graph_s = shard_corpus_for_mesh(corpus_p, graph_p, mesh)
     cons = equal_constraint(qlab, 5)
     search = make_distributed_search(mesh, PARAMS)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         res4 = search(corpus_s, graph_s, queries, cons)
         res5 = search(corpus_s, graph_s, queries, cons, None)  # uniform call
     np.testing.assert_array_equal(np.asarray(res4.ids), np.asarray(res5.ids))

@@ -19,7 +19,14 @@ from repro.core import (  # noqa: E402
     unequal_pct_constraint,
 )
 from repro.data.synthetic import make_labeled_corpus  # noqa: E402
-from repro.graph.build import build_knn_graph, medoid, nn_descent  # noqa: E402
+from repro.graph import build  # noqa: E402
+from repro.graph.build import (  # noqa: E402
+    add_reverse_edges,
+    build_knn_graph,
+    medoid,
+    nn_descent,
+    smallest_k,
+)
 from repro.graph.index import build_index  # noqa: E402
 
 
@@ -60,6 +67,69 @@ def test_nn_descent_recall_reasonable():
         hits += len(set(e_row.tolist()) & set(a_row[a_row >= 0].tolist()))
         total += len(e_row)
     assert hits / total > 0.6, hits / total
+
+
+@pytest.mark.parametrize("chunk,group", [(20, 2), (33, 3), (64, 5), (16, 7)])
+def test_smallest_k_chunked_equals_top_k(chunk, group):
+    # Integer-valued scores force heavy ties: the grouped reduction must
+    # break them exactly like one top_k (lower column first), at every
+    # depth of its recursion.
+    d = jax.random.randint(jax.random.PRNGKey(chunk), (5, 3000), 0, 20)
+    d = d.astype(jnp.float32).at[:, 3].set(jnp.inf).at[4].set(jnp.inf)
+    dist, cols = smallest_k(d, 10, chunk=chunk, group=group)
+    neg, want = jax.lax.top_k(-d, 10)
+    np.testing.assert_array_equal(np.asarray(cols), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(dist), -np.asarray(neg))
+
+
+def test_knn_graph_chunked_top_k_matches_one_pass(monkeypatch):
+    x = _rand_vectors(300, 6, seed=6)
+    want = np.asarray(build_knn_graph(x, degree=8))
+    monkeypatch.setattr(build, "TOPK_CHUNK", 16)
+    monkeypatch.setattr(build, "TOPK_GROUP", 4)
+    jax.clear_caches()
+    np.testing.assert_array_equal(np.asarray(build_knn_graph(x, degree=8)), want)
+
+
+def _reverse_edges_loop(nbrs, vectors, degree):
+    """The per-edge Python loop add_reverse_edges replaced (the reference)."""
+    nbrs = np.asarray(nbrs)
+    n = nbrs.shape[0]
+    rev_lists = [[] for _ in range(n)]
+    for u in range(n):
+        for v in nbrs[u]:
+            if v >= 0:
+                rev_lists[v].append(u)
+    max_rev = max(1, max(len(r) for r in rev_lists))
+    rev = np.full((n, max_rev), -1, dtype=np.int32)
+    for u, lst in enumerate(rev_lists):
+        rev[u, : len(lst)] = lst
+    cand = jnp.concatenate([jnp.asarray(nbrs), jnp.asarray(rev)], axis=-1)
+    rows = jnp.asarray(vectors)[jnp.maximum(cand, 0)]
+    d = jnp.sum((rows - jnp.asarray(vectors)[:, None, :]) ** 2, axis=-1)
+    d = jnp.where((cand < 0) | (cand == jnp.arange(n)[:, None]), jnp.inf, d)
+    out, _ = build._dedup_sorted_by_dist(cand, d, degree)
+    return np.asarray(out)
+
+
+# 6 * 8 * 4 * 64: 64-row edge-distance blocks, several chunks per class
+@pytest.mark.parametrize("chunk_bytes", [None, 6 * 8 * 4 * 64])
+def test_reverse_edges_match_loop_reference(monkeypatch, chunk_bytes):
+    x = _rand_vectors(300, 8, seed=5)
+    g = build_knn_graph(x, degree=6)
+    want = _reverse_edges_loop(g, x, 6)
+    if chunk_bytes is not None:  # several chunks per width class
+        monkeypatch.setattr(build, "REVERSE_CHUNK_BYTES", chunk_bytes)
+    np.testing.assert_array_equal(np.asarray(add_reverse_edges(g, x, 6)), want)
+
+
+def test_nn_descent_row_blocks_match_one_block(monkeypatch):
+    x = _rand_vectors(300, 8, seed=7)
+    want = np.asarray(nn_descent(jax.random.PRNGKey(3), x, degree=6, iters=3))
+    monkeypatch.setattr(build, "NND_ROW_BLOCK", 64)
+    jax.clear_caches()
+    got = np.asarray(nn_descent(jax.random.PRNGKey(3), x, degree=6, iters=3))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_medoid_is_central():

@@ -90,15 +90,12 @@ def test_invalid_pushes_are_ignored():
 def merge_cases(draw):
     cap = draw(st.integers(2, 16))
     n_live = draw(st.integers(0, 16))
-    live = sorted(
-        draw(
-            st.lists(
-                st.floats(0.0, 2.0**10, width=32), min_size=n_live, max_size=n_live
-            )
-        )
-    )
+    # XLA flushes subnormals to zero on both backends, so they are kept out:
+    # a flushed value would compare equal to 0.0 in one path and not the other.
+    dist = st.floats(0.0, 2.0**10, width=32, allow_subnormal=False)
+    live = sorted(draw(st.lists(dist, min_size=n_live, max_size=n_live)))
     m = draw(st.integers(1, 12))
-    new = draw(st.lists(st.floats(0.0, 2.0**10, width=32), min_size=m, max_size=m))
+    new = draw(st.lists(dist, min_size=m, max_size=m))
     valid = draw(st.lists(st.booleans(), min_size=m, max_size=m))
     # duplicate some values across queue and run to force tie-breaking
     if live and draw(st.booleans()):

@@ -183,8 +183,11 @@ def test_mutation_broadcast_epoch_consistent(world):
     corpus, graph = world
     tier = _tier(corpus, graph, n=2, streaming=True)
     vec = np.asarray(corpus.vectors)[3] + 0.01
+    # the new vector joins its neighbourhood's label, so a search for that
+    # label starts next to it
+    label = int(np.asarray(corpus.labels)[3])
 
-    handles = tier.submit_upsert(vec, label=1)
+    handles = tier.submit_upsert(vec, label=label)
     assert [i for i, _ in handles] == [0, 1]
     tier.step_all(force=True)
     responses = tier.poll_all(handles)
@@ -198,7 +201,7 @@ def test_mutation_broadcast_epoch_consistent(world):
     # on every replica
     slot = slots.pop()
     queries = [
-        rt.submit(vec, 4, "label", label_words_row([1], L))
+        rt.submit(vec, 4, "label", label_words_row([label], L))
         for rt in tier.replicas
     ]
     tier.drain()
@@ -216,7 +219,7 @@ def test_mutation_broadcast_epoch_consistent(world):
     assert all(r is not None and r.filled == 1 for r in responses)
     assert len(set(tier.epochs())) == 1
     queries = [
-        rt.submit(vec, 4, "label", label_words_row([1], L))
+        rt.submit(vec, 4, "label", label_words_row([label], L))
         for rt in tier.replicas
     ]
     tier.drain()

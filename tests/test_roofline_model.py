@@ -7,8 +7,8 @@
 """
 import jax
 import jax.numpy as jnp
+import pytest
 
-from repro.common.compat import cost_analysis_dict
 from repro.distributed.meshinfo import single_device_meshinfo
 from repro.models.transformer.model import TransformerConfig, forward_hidden, init_params
 from repro.roofline.model import (
@@ -31,8 +31,8 @@ def test_xla_cost_analysis_undercounts_scans():
 
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     w = jax.ShapeDtypeStruct((64, 64), jnp.float32)
-    f10 = cost_analysis_dict(jax.jit(f_scan).lower(x, w).compile())["flops"]
-    f1 = cost_analysis_dict(jax.jit(f_once).lower(x, w).compile())["flops"]
+    f10 = jax.jit(f_scan).lower(x, w).compile().cost_analysis()["flops"]
+    f1 = jax.jit(f_once).lower(x, w).compile().cost_analysis()["flops"]
     # the artifact: 10 iterations counted ~once (tiny loop-counter ops only)
     assert f10 < 1.5 * f1
 
@@ -52,7 +52,7 @@ def test_analytic_lm_flops_matches_measured_single_layer():
         h = forward_hidden(p, cfg, MI, t)
         return (h[:, -1] @ p["lm_head"]["w"]).astype(jnp.float32)
 
-    measured = cost_analysis_dict(jax.jit(fwd).lower(params, toks).compile())["flops"]
+    measured = jax.jit(fwd).lower(params, toks).compile().cost_analysis()["flops"]
     f, _, _, mf = lm_prefill_terms(cfg, b, s, chips=1)
     # last-position logits only in the probe; analytic assumes full-seq CE.
     # Compare the dominant matmul component instead.
@@ -87,3 +87,12 @@ def test_param_count_consistency_with_analytic():
     n = cfg.param_count()
     assert total == active  # dense model
     assert abs(total - n) / n < 0.02  # norms are the only non-matmul params
+
+
+def test_kernel_bound_refuses_an_unknown_tpu():
+    from repro.roofline.model import TPU_PEAKS, chip_peaks
+
+    assert chip_peaks("tpu", "TPU v5 lite") == TPU_PEAKS["TPU v5 lite"]
+    with pytest.raises(ValueError, match="device kind"):
+        chip_peaks("tpu", "TPU v4")
+    assert chip_peaks("cpu") == chip_peaks("cpu", "anything")

@@ -27,6 +27,7 @@ from repro.obs.promparse import (
 from repro.obs.tracing import (
     STAGES,
     RequestTrace,
+    span,
     stage_sum,
     trace_consistent,
 )
@@ -51,6 +52,7 @@ __all__ = [
     "parse_exposition",
     "rollup_samples",
     "runtime_families",
+    "span",
     "stage_sum",
     "trace_consistent",
 ]

@@ -37,6 +37,10 @@ from repro.obs.metrics import MetricsRegistry, Sample, format_value
 Labels = Tuple[Tuple[str, str], ...]
 FamilyFn = Callable[..., List[Sample]]
 
+# Telemetry counters with this suffix are microseconds of a host stage;
+# they are exposed as one seconds family, not as events.
+HOST_US = "_us"
+
 
 def latency_hist_samples(
     hist, labels: Tuple[Tuple[str, str], ...] = ()
@@ -77,13 +81,35 @@ def runtime_families(
         return [
             ("", labels + (("event", key),), float(tel.counters[key]))
             for key in sorted(tel.counters)
+            if not key.endswith(HOST_US)
         ]
 
     fams.append((
         f"{ns}_serving_events_total", "counter",
         "Lifecycle event counters (Telemetry.counters): submitted, "
-        "completed, goodput, shed_*, fault_*, routed_*, epoch_swaps, ...",
+        "completed, goodput, shed_*, fault_*, routed_*, epoch_swaps, "
+        "the *_n counts of the host stages, ...",
         counter_samples,
+    ))
+
+    def host_samples(labels: Labels = ()) -> List[Sample]:
+        return [
+            (
+                "",
+                labels + (("stage", key[: -len(HOST_US)]),),
+                tel.counters[key] / 1e6,
+            )
+            for key in sorted(tel.counters)
+            if key.endswith(HOST_US)
+        ]
+
+    fams.append((
+        f"{ns}_serving_host_seconds_total", "counter",
+        "Host time by serving stage (the Telemetry.counters *_us sums, in "
+        "seconds): http_parse | http_admit | http_reply_lag | http_reply | "
+        "assemble | dispatch | device_wait | readback | complete | "
+        "host_turn | queue_wait",
+        host_samples,
     ))
 
     def verdict_samples(labels: Labels = ()) -> List[Sample]:

@@ -41,9 +41,15 @@ import json
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Optional
+from typing import Callable, Optional, Union
 
 import numpy as np
+
+from repro.obs.tracing import span
+
+# The front end's spans read the clock ``serving.types.wall_clock`` reads,
+# so they line up with the runtime's on one timeline.
+_clock = time.perf_counter
 
 
 def _response_payload(resp, replica: Optional[int] = None) -> dict:
@@ -231,61 +237,104 @@ class ServingFrontend:
         }
 
     # --- request handling (called from handler threads) -------------------
-    def handle_search(self, payload: dict) -> tuple:
+    def handle_search(
+        self,
+        payload: Union[dict, Callable[[], bytes]],
+        send: Optional[Callable[[int, dict], None]] = None,
+    ) -> tuple:
+        """One search through the front end's stages, each a span inside
+        ``repro.http.search``: parse (``payload`` is the decoded object or
+        the handler's body reader, read and decoded here), admit (replica
+        lock + submit), await (the whole poll loop) and reply (payload
+        building and ``send``, the handler's writer, when given). Returns
+        ``(status, body)``. An answered search adds its stage times to the
+        serving replica's telemetry (``Telemetry.on_http_search``)."""
         from repro.serving.types import AdmissionError
 
-        try:
-            query = np.asarray(payload["query"], dtype=np.float32)
-            k = int(payload.get("k", 10))
-            family = str(payload["family"])
-            operand = self._parse_operand(family, payload)
-        except (KeyError, TypeError, ValueError) as e:
-            return 400, {"error": f"bad request: {e}"}
-        timeout_s = float(payload.get("timeout_s", self.default_timeout_s))
-        if not self._accepting:
-            return 503, {"error": "shutting down"}
-        deadline_s = None
-        if payload.get("deadline_ms") is not None:
-            deadline_s = float(payload["deadline_ms"]) / 1e3
-        try:
-            if self.tier is not None:
-                replica, req_id = self.tier.submit(
-                    query, k, family, operand, deadline_s=deadline_s
-                )
-            else:
-                replica = 0
-                with self.locks[0]:
-                    deadline = (
-                        self.runtime.clock() + deadline_s
-                        if deadline_s is not None else None
+        def reply(status: int, body: dict) -> tuple:
+            if send is not None:
+                send(status, body)
+            return status, body
+
+        with span("repro.http.search", clock=_clock) as whole:
+            with span("repro.http.parse", clock=_clock) as parse:
+                try:
+                    if callable(payload):
+                        payload = _decode_body(payload())
+                    query = np.asarray(payload["query"], dtype=np.float32)
+                    k = int(payload.get("k", 10))
+                    family = str(payload["family"])
+                    operand = self._parse_operand(family, payload)
+                    timeout_s = float(
+                        payload.get("timeout_s", self.default_timeout_s)
                     )
-                    req_id = self.runtime.submit(
-                        query, k, family, operand, deadline=deadline
+                    deadline_s = None
+                    if payload.get("deadline_ms") is not None:
+                        deadline_s = float(payload["deadline_ms"]) / 1e3
+                    error = None
+                except _BadBody as e:
+                    error = f"bad JSON body: {e}"
+                except (KeyError, TypeError, ValueError) as e:
+                    error = f"bad request: {e}"
+            if error is not None:
+                return reply(400, {"error": error})
+            if not self._accepting:
+                return reply(503, {"error": "shutting down"})
+            with span("repro.http.admit", clock=_clock) as admit:
+                try:
+                    replica, req_id = self._admit_search(
+                        query, k, family, operand, deadline_s
                     )
-        except AdmissionError as e:
-            return 429, {"error": str(e)}
-        except (TypeError, ValueError) as e:
-            return 400, {"error": f"bad request: {e}"}
-        with self._meta_lock:
-            self.started_requests += 1
-        give_up = time.monotonic() + timeout_s
-        while time.monotonic() < give_up:
-            with self.locks[replica]:
-                resp = self.runtimes[replica].poll(req_id)
-            if resp is not None:
-                return 200, _response_payload(
+                except AdmissionError as e:
+                    return reply(429, {"error": str(e)})
+                except (TypeError, ValueError) as e:
+                    return reply(400, {"error": f"bad request: {e}"})
+                admit.annotate(req_id=req_id)
+            whole.annotate(req_id=req_id, replica=replica)
+            with self._meta_lock:
+                self.started_requests += 1
+            runtime = self.runtimes[replica]
+            resp = None
+            with span("repro.http.await", clock=_clock, req_id=req_id):
+                give_up = time.monotonic() + timeout_s
+                while time.monotonic() < give_up:
+                    with self.locks[replica]:
+                        resp = runtime.poll(req_id)
+                        if resp is not None:
+                            lag = runtime.clock() - resp.complete_t
+                            break
+                    time.sleep(self.pump_interval)
+            if resp is None:
+                return reply(504, {
+                    "error": "timed out waiting for completion",
+                    "req_id": req_id,
+                    "replica": replica if self.tier is not None else None,
+                })
+            with span("repro.http.reply", clock=_clock, req_id=req_id) as out:
+                status, body = reply(200, _response_payload(
                     resp,
                     replica=(
                         replica if self.tier is not None
                         else self.runtime.replica_id
                     ),
-                )
-            time.sleep(self.pump_interval)
-        return 504, {
-            "error": "timed out waiting for completion",
-            "req_id": req_id,
-            "replica": replica if self.tier is not None else None,
-        }
+                ))
+            runtime.telemetry.on_http_search(
+                parse.elapsed, admit.elapsed, lag, out.elapsed
+            )
+        return status, body
+
+    def _admit_search(self, query, k, family, operand, deadline_s):
+        """Submit to the routed replica under its lock: (replica, req_id)."""
+        if self.tier is not None:
+            return self.tier.submit(query, k, family, operand, deadline_s=deadline_s)
+        with self.locks[0]:
+            deadline = (
+                self.runtime.clock() + deadline_s
+                if deadline_s is not None else None
+            )
+            return 0, self.runtime.submit(
+                query, k, family, operand, deadline=deadline
+            )
 
     def handle_mutation(self, kind: str, payload: dict) -> tuple:
         """Streaming upsert/delete over the wire. On a tier the mutation
@@ -440,6 +489,20 @@ class ServingFrontend:
         return 200, report
 
 
+class _BadBody(ValueError):
+    """The request body is not a JSON object."""
+
+
+def _decode_body(raw: bytes) -> dict:
+    try:
+        payload = json.loads(raw or b"{}")
+    except ValueError as e:  # JSONDecodeError, bad UTF-8
+        raise _BadBody(e) from e
+    if not isinstance(payload, dict):
+        raise _BadBody("body must be a JSON object")
+    return payload
+
+
 class _Handler(BaseHTTPRequestHandler):
     frontend: ServingFrontend  # bound per server in ServingFrontend.start
     protocol_version = "HTTP/1.1"
@@ -481,23 +544,21 @@ class _Handler(BaseHTTPRequestHandler):
         else:
             self._send_json(404, {"error": f"no route {path!r}"})
 
+    def _read_body(self) -> bytes:
+        return self.rfile.read(int(self.headers.get("Content-Length", 0)))
+
     def do_POST(self) -> None:  # noqa: N802 (stdlib API name)
         path = self.path.split("?", 1)[0]
-        routes = {
-            "/v1/search": lambda p: self.frontend.handle_search(p),
-            "/v1/upsert": lambda p: self.frontend.handle_mutation("upsert", p),
-            "/v1/delete": lambda p: self.frontend.handle_mutation("delete", p),
-        }
-        handler = routes.get(path)
-        if handler is None:
+        if path == "/v1/search":
+            # The search route reads its own body, inside its parse span.
+            self.frontend.handle_search(self._read_body, send=self._send_json)
+            return
+        if path not in ("/v1/upsert", "/v1/delete"):
             self._send_json(404, {"error": f"no route {path!r}"})
             return
         try:
-            length = int(self.headers.get("Content-Length", 0))
-            payload = json.loads(self.rfile.read(length) or b"{}")
-            if not isinstance(payload, dict):
-                raise ValueError("body must be a JSON object")
-        except (ValueError, json.JSONDecodeError) as e:
+            payload = _decode_body(self._read_body())
+        except ValueError as e:  # a bad Content-Length too
             self._send_json(400, {"error": f"bad JSON body: {e}"})
             return
-        self._send_json(*handler(payload))
+        self._send_json(*self.frontend.handle_mutation(path[len("/v1/"):], payload))

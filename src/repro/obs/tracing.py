@@ -22,10 +22,17 @@ accumulating into the same trace):
 
 ``breakdown()`` is what lands on ``Response.trace``; per-stage histograms
 are fed from it by ``Telemetry.on_complete``.
+
+``span`` marks one stage of the serving path on the profiler's timeline
+(``jax.profiler.TraceAnnotation``, the clock the device planes of a trace
+share) and, optionally, adds its elapsed time to a counter dict. The span
+names and counters are listed in DESIGN.md §12.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import Callable, List, MutableMapping, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 STAGES = ("queue_wait", "batch_wait", "execute", "overhead")
 
@@ -117,3 +124,59 @@ def trace_consistent(trace: dict, rel_tol: float = 0.01) -> bool:
     covers ~zero-latency sheds)."""
     total = float(trace["total"])
     return abs(stage_sum(trace) - total) <= max(rel_tol * total, 1e-9)
+
+
+class span:
+    """One named stage on the profiler's timeline.
+
+    ``with span("repro.search.device", counters, "device_wait",
+    clock=wall_clock, batch_id=7) as sp:`` enters
+    ``TraceAnnotation(name, **args)`` (free unless a profiler session is
+    recording), and on exit adds the elapsed microseconds to
+    ``counters[key + "_us"]`` and 1 to ``counters[key + "_n"]`` when
+    ``counters`` is given, even if the block raised. ``sp.start`` and
+    ``sp.end`` are the two ``clock`` readings, for callers that stamp a
+    ``RequestTrace`` with the same instants. ``annotate`` attaches args
+    known only inside the span (a request id assigned at admission).
+    """
+
+    __slots__ = ("name", "args", "counters", "key", "clock", "start", "end", "_ann")
+
+    def __init__(
+        self,
+        name: str,
+        counters: Optional[MutableMapping[str, float]] = None,
+        key: Optional[str] = None,
+        *,
+        clock: Callable[[], float],
+        **args,
+    ):
+        self.name = name
+        self.args = args
+        self.counters = counters
+        self.key = key
+        self.clock = clock
+        self.start = self.end = None
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        # A TraceMe starts at construction: build it here, not in __init__.
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.start = self.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end = self.clock()
+        self._ann.__exit__(*exc)
+        if self.counters is not None:
+            self.counters[self.key + "_us"] += 1e6 * (self.end - self.start)
+            self.counters[self.key + "_n"] += 1
+        return False
+
+    def annotate(self, **args) -> None:
+        self._ann.set_metadata(**args)
+
+    @property
+    def elapsed(self) -> float:
+        return self.end - self.start

@@ -43,7 +43,7 @@ from repro.core.posting import (
 from repro.core.router import RouterConfig, StrategyRouter
 from repro.core.types import Corpus, GraphIndex, SearchParams, SearchResult
 from repro.obs.logs import JsonLogger
-from repro.obs.tracing import RequestTrace
+from repro.obs.tracing import RequestTrace, span
 from repro.serving.batcher import BATCH_LADDER, DynamicBatcher, MicroBatch
 from repro.serving.cache import CompileCache
 from repro.serving.controller import AdaptiveController, make_tier_ladder
@@ -436,6 +436,12 @@ class ServingRuntime:
         if logger is not None and logger.clock is None:
             logger.clock = self.clock
         self._next_batch_id = 0
+        # Closure keys already dispatched (warm-up included): a dispatch
+        # span says ``cold=1`` on a key's first call. ``_readback_end`` is
+        # when the last query batch's results reached the host, the start
+        # of the host's turn that ends at the next dispatch.
+        self._dispatched: set = set()
+        self._readback_end: Optional[float] = None
 
     def _log(self, event: str, **fields) -> None:
         if self.logger is not None:
@@ -462,6 +468,7 @@ class ServingRuntime:
             for tier in range(len(self.controller.tiers)):
                 for bucket in self.ladder:
                     fn = self.cache.get((bucket, family, tier))
+                    self._dispatched.add(("graph", bucket, family, tier))
                     queries = jnp.zeros((bucket, dim), jnp.float32)
                     if family == "label":
                         cons = LabelSetConstraint(
@@ -638,16 +645,22 @@ class ServingRuntime:
         unmeetable) requests before any compute is spent on it.
         """
         self.controller.observe_load(self.batcher.pending_count())
-        done = 0
         t_flush = self.clock()
         batches = self.batcher.flush(t_flush, force=force)
+        if not batches:
+            return 0  # the pump's idle tick: no span, no counter
+        with span("repro.runtime.step", clock=wall_clock, n_batches=len(batches)):
+            return self._run_flush(batches, t_flush)
+
+    def _run_flush(self, batches: List[MicroBatch], t_flush: float) -> int:
+        done = 0
         for mb in batches:
             mb.batch_id = self._next_batch_id
             self._next_batch_id += 1
             for r in mb.requests:
+                # Everything since (re-)enqueue was batcher queue wait.
+                self.telemetry.on_queue_wait(max(t_flush - r.enqueue_t, 0.0))
                 if r.trace is not None:
-                    # Span accounting at the flush boundary: everything
-                    # since (re-)enqueue was batcher queue wait.
                     r.trace.on_flush(r.enqueue_t, t_flush)
         mutations = [mb for mb in batches if mb.family in MUTATION_FAMILIES]
         queries = [mb for mb in batches if mb.family not in MUTATION_FAMILIES]
@@ -886,46 +899,96 @@ class ServingRuntime:
         # assembly + host->device transfer + search + result readback. A
         # virtual-time replay charges all of it to the timeline — this is
         # exactly the per-request overhead the batch=1 baseline cannot
-        # amortize.
-        t_start = self.clock()
-        t0 = wall_clock()
+        # amortize. Each stage is a span on the wall clock; a wall-clock
+        # runtime stamps its traces with the span boundaries themselves.
+        virtual = self.clock is not wall_clock
+        t_start = self.clock() if virtual else None
+        tel = self.telemetry.counters
+        bid = mb.batch_id
         c0 = cpu_clock()
         try:
-            queries = assemble_queries(mb, self.executor.dim)
-            constraint = assemble_constraint(mb)
-            strategy = mb.strategy
-            res = None
-            if strategy == "posting":
-                res = self._run_posting(mb, queries, constraint)
-            elif strategy == "overlay":
-                res = self._run_overlay(mb, queries)
-            if res is None:
-                # graph strategy, or a routed strategy that turned out
-                # inapplicable at dispatch time (e.g. the label's posting
-                # set shrank below the overlay minimum under churn): the
-                # full traversal is the universal fallback.
-                strategy = "graph"
-                fn = self.cache.get((mb.bucket, mb.family, mb.tier))
-                res = fn(queries, constraint)
-            jax.block_until_ready(res.dists)
+            with span("repro.runtime.assemble", tel, "assemble",
+                      clock=wall_clock, batch_id=bid) as asm:
+                queries = assemble_queries(mb, self.executor.dim)
+                constraint = assemble_constraint(mb)
+            key = (mb.strategy, mb.bucket, mb.family, mb.tier)
+            cold = key not in self._dispatched
+            self._dispatched.add(key)
+            with span("repro.search.dispatch", tel, "dispatch", clock=wall_clock,
+                      batch_id=bid, bucket=mb.bucket, family=mb.family,
+                      tier=mb.tier, cold=int(cold)) as disp:
+                if self._readback_end is not None:
+                    self.telemetry.on_host_turn(disp.start - self._readback_end)
+                res, strategy = self._dispatch(mb, queries, constraint)
+            with span("repro.search.device", tel, "device_wait",
+                      clock=wall_clock, batch_id=bid):
+                jax.block_until_ready(res.dists)
         except ExecutorFault as fault:
             # The recovery contract: a faulted dispatch costs its wall
             # time, its requests are retried through the batcher within
             # their budget, and budget-exhausted ones surface as FAILED
             # responses — a fault never hangs or loses a request.
-            dt = wall_clock() - t0
+            dt = wall_clock() - asm.start
             self.busy_seconds += cpu_clock() - c0
+            self._readback_end = None  # no host turn spans a fault
             if hasattr(self.clock, "advance"):
                 self.clock.advance(dt)
-            return self._recover_faulted(mb, fault, t_start)
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        dt = wall_clock() - t0
+            return self._recover_faulted(
+                mb, fault, t_start if virtual else asm.start
+            )
+        with span("repro.search.readback", tel, "readback",
+                  clock=wall_clock, batch_id=bid) as rb:
+            # Every device-to-host read of the batch, in one transfer.
+            ids, dists, iters = jax.device_get(
+                (res.ids, res.dists, res.stats.iters)
+            )
+        self._readback_end = rb.end
+        dt = rb.end - asm.start
         self.busy_seconds += cpu_clock() - c0
-        if hasattr(self.clock, "advance"):
-            # Virtual-time replay: execution cost advances the timeline.
-            self.clock.advance(dt)
-        now = self.clock()
+        if virtual:
+            if hasattr(self.clock, "advance"):
+                # Virtual-time replay: execution cost advances the timeline.
+                self.clock.advance(dt)
+            now = self.clock()
+        else:
+            t_start, now = asm.start, rb.end
+        with span("repro.runtime.complete", tel, "complete",
+                  clock=wall_clock, batch_id=bid):
+            return self._complete(
+                mb, strategy, ids, dists, float(iters), t_start, now, dt
+            )
+
+    def _dispatch(self, mb: MicroBatch, queries, constraint):
+        """Call the batch's executor: the routed strategy, else the graph
+        closure. Returns the (device-resident) result and the strategy
+        that ran."""
+        res = None
+        if mb.strategy == "posting":
+            res = self._run_posting(mb, queries, constraint)
+        elif mb.strategy == "overlay":
+            res = self._run_overlay(mb, queries)
+        if res is not None:
+            return res, mb.strategy
+        # graph strategy, or a routed strategy that turned out
+        # inapplicable at dispatch time (e.g. the label's posting
+        # set shrank below the overlay minimum under churn): the
+        # full traversal is the universal fallback.
+        fn = self.cache.get((mb.bucket, mb.family, mb.tier))
+        return fn(queries, constraint), "graph"
+
+    def _complete(
+        self,
+        mb: MicroBatch,
+        strategy: str,
+        ids: np.ndarray,
+        dists: np.ndarray,
+        mean_iters: float,
+        t_start: float,
+        now: float,
+        dt: float,
+    ) -> int:
+        """Responses, escalations, telemetry and controller feedback for
+        one executed query batch, from its host-side results."""
         # Execution-only duration (injected spikes excluded — they advance
         # the virtual clock, not the measured wall interval): the ladder's
         # predictive-shedding estimate of what one more dispatch costs.
@@ -947,10 +1010,9 @@ class ServingRuntime:
             exec_s=round(dt, 9),
         )
 
-        mean_iters = float(res.stats.iters)
         # ids rows are -1-padded at the tail (ascending dists), so the fill
         # within a request's k-prefix is min(total filled, k).
-        filled_rows = np.minimum(np.asarray(res.filled),
+        filled_rows = np.minimum(np.sum(ids >= 0, axis=-1),
                                  [r.k for r in mb.requests] + [0] * mb.n_padded)
         fill_fracs = []
         done = 0

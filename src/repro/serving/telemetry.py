@@ -20,6 +20,7 @@ bounded response window.
 from __future__ import annotations
 
 import math
+import threading
 from collections import Counter, deque
 from typing import Deque, Dict, List, Optional, Sequence
 
@@ -119,6 +120,9 @@ class Telemetry:
         # (queue_wait | batch_wait | execute | overhead) when the runtime
         # runs with tracing on — where a p99 outlier spent its time.
         self.stage_hists: Dict[str, LatencyHistogram] = {}
+        # HTTP handler threads hold no replica lock when a request ends;
+        # they add the front end's counters under this one.
+        self._http_lock = threading.Lock()
 
     # --- event hooks (runtime calls these) --------------------------------
     def on_submit(self) -> None:
@@ -132,6 +136,31 @@ class Telemetry:
         self.counters["dispatched_slots"] += bucket
         self.counters["dispatched_real"] += n_real
         self.counters["padded_slots"] += bucket - n_real
+
+    def on_queue_wait(self, seconds: float) -> None:
+        """One request's batcher wait, enqueue to flush (the same numbers
+        ``RequestTrace.on_flush`` gets, kept with tracing off too)."""
+        self.counters["queue_wait_us"] += 1e6 * seconds
+
+    def on_host_turn(self, seconds: float) -> None:
+        """Host time between one query batch's read-back end and the next
+        query batch's dispatch: the device waits on the host for it."""
+        self.counters["host_turn_us"] += 1e6 * seconds
+        self.counters["host_turn_n"] += 1
+
+    def on_http_search(
+        self, parse_s: float, admit_s: float, reply_lag_s: float, reply_s: float
+    ) -> None:
+        """One search request's front-end stages, from its handler thread:
+        body parse, admission (replica lock + submit), the wait from the
+        response's completion to the handler holding it, and the reply."""
+        with self._http_lock:
+            c = self.counters
+            c["http_requests"] += 1
+            c["http_parse_us"] += 1e6 * parse_s
+            c["http_admit_us"] += 1e6 * admit_s
+            c["http_reply_lag_us"] += 1e6 * reply_lag_s
+            c["http_reply_us"] += 1e6 * reply_s
 
     def on_escalate(self) -> None:
         self.counters["escalations"] += 1

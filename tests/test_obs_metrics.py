@@ -199,8 +199,18 @@ def test_scrape_matches_telemetry_exactly(served_runtime):
     fams = parse_exposition(instrument_runtime(rt).render_prometheus())
     tel = rt.telemetry
     events = fams["repro_serving_events_total"]
+    host = fams["repro_serving_host_seconds_total"]
     for key, v in tel.counters.items():
-        assert events.value(event=key) == v, key
+        if key.endswith("_us"):
+            # Host-stage microseconds: one seconds family, not events.
+            assert host.value(stage=key[:-3]) == v / 1e6, key
+        else:
+            assert events.value(event=key) == v, key
+    host_stages = {k[:-3] for k in tel.counters if k.endswith("_us")}
+    assert set(host.label_values("stage")) == host_stages
+    assert {"assemble", "dispatch", "device_wait", "readback", "complete",
+            "queue_wait", "host_turn"} <= host_stages
+    assert not any(e.endswith("_us") for e in events.label_values("event"))
     lat = fams["repro_serving_latency_seconds"]
     assert lat.hist_count() == tel.latency_hist.total
     assert lat.hist_sum() == tel.latency_hist.sum

@@ -5,13 +5,20 @@ frontier as a distance-ascending sorted array of static capacity ``C``:
 
   * empty slots hold ``(+inf, -1)``
   * ``pop``  == take the head, shift everything left by one
-  * ``push`` == concatenate, argsort, truncate back to ``C``
+  * ``push`` == concatenate, keep the ``C`` smallest, ascending
 
 All operations carry a leading batch axis ``B`` (one queue per query) so the
-whole query batch advances in lock-step (DESIGN.md §2). Sorting ``C + M`` keys
-per step is a small sorting network on TPU — for typical ``C`` in [64, 512]
-and graph degree ``M`` in [16, 64] this is far cheaper than the
-neighbor-distance gathers.
+whole query batch advances in lock-step (DESIGN.md §2).
+
+``queue_push`` has one lowering per platform, chosen from
+``jax.default_backend()`` at trace time; both give the same queue, bit for
+bit. XLA:CPU selects with ``top_k`` over the ``C + M`` keys and gathers the
+ids by the returned positions. On TPU that id gather runs one element at a
+time: at C = 1024 it took ~330 us against ~23 us for the sort itself (87%
+of a search iteration on a v5e), so there one stable sort carries the ids
+beside the distances and the queue is a static slice of its output. On
+XLA:CPU that sort measured 1.9-4x slower per push than ``top_k`` + gather,
+for (B, C, M) from (8, 64, 16) to (32, 1024, 32).
 """
 from __future__ import annotations
 
@@ -116,18 +123,35 @@ def queue_push(
     """Insert up to M new elements per row; keep the best ``C``.
 
     new_d: (B, M) f32, new_i: (B, M) i32, valid: (B, M) bool.
-    Invalid entries are masked to (+inf, -1) before the merge.
+    Invalid entries are masked to (+inf, -1) before the merge. Ties keep
+    queue elements first, then the new ones in slot order. On TPU the
+    ids ride in a stable sort (``_push_by_sort``); elsewhere ``top_k``
+    selects positions and the ids are gathered by them
+    (``_push_by_top_k``); the queues are identical (module docstring).
     """
     nd = jnp.where(valid, new_d, INF).astype(q.dists.dtype)
     ni = jnp.where(valid, new_i, PAD_ID).astype(q.ids.dtype)
     all_d = jnp.concatenate([q.dists, nd], axis=-1)  # (B, C+M)
     all_i = jnp.concatenate([q.ids, ni], axis=-1)
+    if jax.default_backend() == "tpu":
+        return _push_by_sort(all_d, all_i, q.capacity)
+    return _push_by_top_k(all_d, all_i, q.capacity)
+
+
+def _push_by_top_k(all_d: Array, all_i: Array, c: int) -> BatchedQueue:
     # top_k of the negated keys = the C smallest, already ascending — a
     # partial selection network instead of a full (C+M) sort. Measured
-    # 3.3x faster end-to-end search on CPU (EXPERIMENTS.md §Perf D5); on
-    # TPU top_k lowers to a cheaper selection than the full bitonic sort.
-    neg, pos = jax.lax.top_k(-all_d, q.capacity)
+    # 3.3x faster end-to-end search on CPU (EXPERIMENTS.md §Perf D5).
+    neg, pos = jax.lax.top_k(-all_d, c)
     return BatchedQueue(dists=-neg, ids=jnp.take_along_axis(all_i, pos, axis=-1))
+
+
+def _push_by_sort(all_d: Array, all_i: Array, c: int) -> BatchedQueue:
+    # A stable ascending sort keeps the lower index first on ties, as
+    # top_k does, and +inf padding last; the ids travel as a second
+    # operand, so no per-element gather follows.
+    d, i = jax.lax.sort((all_d, all_i), dimension=-1, is_stable=True, num_keys=1)
+    return BatchedQueue(dists=d[:, :c], ids=i[:, :c])
 
 
 # ---------------------------------------------------------------------------

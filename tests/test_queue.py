@@ -1,4 +1,5 @@
 """Property tests for the fixed-capacity sorted-array priority queues."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -170,3 +171,84 @@ def test_topk_threshold_inf_until_full():
         jnp.ones((1, 3), bool),
     )
     assert float(q.topk_threshold(qq, 3)[0]) == 3.0
+
+
+def _ties_case(rng):
+    # distances drawn from four values: ties within every push and between
+    # the queue and each push
+    d = rng.integers(0, 4, size=(6, 4, 9)).astype(np.float32)
+    return 8, d, np.ones(d.shape, bool)
+
+
+def _padding_case(rng):
+    # rows 0 and 2 never get a valid entry (all-invalid rows stay padding);
+    # the others get a few, so the queue keeps +inf slots after the live ones
+    d = rng.random((4, 4, 5)).astype(np.float32)
+    valid = rng.random(d.shape) < 0.3
+    valid[:, [0, 2]] = False
+    d[:, 3, 1] = np.inf  # a valid entry that is itself +inf
+    return 6, d, valid
+
+
+def _m1_case(rng):
+    d = rng.integers(0, 3, size=(10, 3, 1)).astype(np.float32)
+    return 4, d, rng.random(d.shape) < 0.8
+
+
+def _m_ge_c_case(rng):
+    d = rng.integers(0, 6, size=(5, 3, 12)).astype(np.float32)
+    return 4, d, rng.random(d.shape) < 0.7
+
+
+def _capacity1_case(rng):
+    d = rng.integers(0, 3, size=(6, 3, 4)).astype(np.float32)
+    return 1, d, rng.random(d.shape) < 0.8
+
+
+def _random_case(rng):
+    # a frontier-sized queue under many pushes, distances on a coarse grid
+    d = (rng.integers(0, 512, size=(30, 8, 16)) / 8.0).astype(np.float32)
+    d[:, :, 0] = 0.0
+    return 64, d, rng.random(d.shape) < 0.9
+
+
+@pytest.mark.parametrize(
+    "make_case",
+    [_ties_case, _padding_case, _m1_case, _m_ge_c_case, _capacity1_case,
+     _random_case],
+    ids=["ties", "padding", "m1", "m_ge_c", "capacity1", "random"],
+)
+def test_push_lowerings_identical(monkeypatch, make_case):
+    """queue_push's TPU lowering (one stable sort carrying the ids) and its
+    top_k + gather lowering give the same queue, bit for bit, after every
+    push; sort_run + queue_merge_sorted gives it too."""
+    rng = np.random.default_rng(14)
+    cap, d, valid = make_case(rng)
+    # distinct ids in no particular order, so a tie broken by id (and not
+    # by position) shows
+    ids = rng.permutation(d.size).astype(np.int32).reshape(d.shape)
+    n_push, b, m = d.shape
+    # one jitted function per lowering, each traced under its own backend
+    by_top_k = jax.jit(lambda qq, *p: q.queue_push(qq, *p))
+    by_sort = jax.jit(lambda qq, *p: q.queue_push(qq, *p))
+    by_merge = jax.jit(lambda qq, *p: q.queue_merge_sorted(qq, *q.sort_run(*p)))
+    ref = got = merged = q.queue_init(b, cap)
+    for t in range(n_push):
+        push = (jnp.asarray(d[t]), jnp.asarray(ids[t]), jnp.asarray(valid[t]))
+        ref = by_top_k(ref, *push)
+        with monkeypatch.context() as mp:
+            mp.setattr(jax, "default_backend", lambda: "tpu")
+            got = by_sort(got, *push)
+        merged = by_merge(merged, *push)
+        for other in (got, merged):
+            np.testing.assert_array_equal(
+                np.asarray(other.dists), np.asarray(ref.dists))
+            np.testing.assert_array_equal(
+                np.asarray(other.ids), np.asarray(ref.ids))
+    assert np.isfinite(np.asarray(ref.dists)).any()
+    # the two lowerings really differ: only the top_k one gathers ids
+    args = (q.queue_init(b, cap), *push)
+    assert "gather" in by_top_k.lower(*args).as_text()
+    with monkeypatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        assert "gather" not in by_sort.lower(*args).as_text()

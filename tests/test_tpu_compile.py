@@ -14,6 +14,7 @@ test worker imports every test file. Keep these tests in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -132,16 +133,9 @@ def test_pq_adc_compiles(spec):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("fuse", ["on", "off"])
-def test_search_step_compiles_and_fits(spec, monkeypatch, fuse):
-    """One whole jitted search at the airship-sift1m shapes. The program
-    asks jax.default_backend() which kernels to dispatch; here that is the
-    CPU, so the test answers "tpu" for the duration of the trace."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    params = SearchParams(
-        mode="prefer", k=16, ef_result=64, ef_sat=64, ef_other=64,
-        n_start=16, max_iters=128, fuse_expand=fuse,
-    )
+def _compile_search(spec, params, b):
+    """Compile one whole jitted search over the 1M x 128 deployment with a
+    batch of ``b`` label-constrained queries."""
 
     def search(corpus, graph, queries, cons):
         ctx = build_context(corpus, cons, queries, params, degree=DEG)
@@ -155,10 +149,48 @@ def test_search_step_compiles_and_fits(spec, monkeypatch, fuse):
         neighbors=spec((N, DEG), i32), sample_ids=spec((SAMPLE,), i32),
         entry_point=spec((), i32),
     )
-    cons = LabelSetConstraint(words=spec((B, (N_LABELS + 31) // 32), u32))
-    compiled = jax.jit(search).lower(
-        corpus, graph, spec((B, D), f32), cons
+    cons = LabelSetConstraint(words=spec((b, (N_LABELS + 31) // 32), u32))
+    return jax.jit(search).lower(
+        corpus, graph, spec((b, D), f32), cons
     ).compile()
+
+
+@pytest.mark.parametrize("fuse", ["on", "off"])
+def test_search_step_compiles_and_fits(spec, monkeypatch, fuse):
+    """One whole jitted search at the airship-sift1m shapes. The program
+    asks jax.default_backend() which kernels to dispatch; here that is the
+    CPU, so the test answers "tpu" for the duration of the trace."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = SearchParams(
+        mode="prefer", k=16, ef_result=64, ef_sat=64, ef_other=64,
+        n_start=16, max_iters=128, fuse_expand=fuse,
+    )
+    compiled = _compile_search(spec, params, B)
     total = _fits(compiled)
     assert total > N * D * 4  # the corpus itself is an argument
     assert ("tpu_custom_call" in compiled.as_text()) == (fuse == "on")
+
+
+def test_frontier_push_gathers_no_ids(spec, monkeypatch):
+    """The unfused search at the bulk serving shape (32 queries, ef 1024)
+    updates its frontiers with a sort that carries the ids: the program
+    holds no per-row id gather to a queue's width ef, nor to the width
+    ef + M that a push sorts (M = degree for the sat/other pushes, 1 for
+    the result list). Each such gather ran one element at a time on the
+    chip, ~330 us at this shape, against ~23 us for the sort beside it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bulk, ef = 32, 1024
+    params = SearchParams(
+        mode="prefer", k=16, ef_result=ef, ef_sat=ef, ef_other=ef,
+        n_start=16, max_iters=4096, fuse_expand="off",
+    )
+    text = _compile_search(spec, params, bulk).as_text()
+    widths = "|".join(str(w) for w in (ef, ef + 1, ef + DEG))
+    id_gathers = re.findall(
+        rf"= s32\[{bulk},(?:{widths})\]\{{[^}}]*\}} gather\(", text)
+    assert id_gathers == []
+    # the pushes are there, as sorts over (ef + M) keys with the ids beside
+    pushes = re.findall(
+        rf"sort\(\S+, \S+, \S+\), dimensions=\{{1\}}, is_stable=true", text)
+    assert len(pushes) >= 3
+    assert f"s32[{bulk},{ef + DEG}]" in text
